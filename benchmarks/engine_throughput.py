@@ -156,7 +156,9 @@ else:
     t0 = time.perf_counter()
     run_seed_group("random", 9, "uniform", seeds, rounds, cfg_fn=cfg,
                    overlap=overlap)
-print(json.dumps({"round_s": (time.perf_counter() - t0) / rounds}))
+import jax
+print(json.dumps({"round_s": (time.perf_counter() - t0) / rounds,
+                  "platform": jax.devices()[0].platform}))
 """
 
 
@@ -190,6 +192,16 @@ def bench_round_overlap() -> List[str]:
     import sys as _sys
     from pathlib import Path as _Path
 
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "engine_overlap times child processes, and this process "
+                f"already holds the {jax.default_backend()} backend; run "
+                "it first or alone: python -m benchmarks.run "
+                "engine_overlap")
+
     rows, per = [], {}
     src = str(_Path(__file__).resolve().parent.parent / "src")
     prev = os.environ.get("PYTHONPATH")
@@ -206,7 +218,8 @@ def bench_round_overlap() -> List[str]:
             got = _json.loads(proc.stdout.strip().splitlines()[-1])
             per[(shape, label)] = got["round_s"]
             rows.append(f"engine_{shape}_{label}_round_s,"
-                        f"{got['round_s']:.3f},n_clients=32;warm;"
+                        f"{got['round_s']:.3f},platform={got['platform']};"
+                        f"n_clients=32;warm;"
                         f"round-ahead={label == 'overlap'};"
                         f"{'4 seeds' if shape == 'sweep' else '1 sim'}")
     single = per[("single", "serial")] / per[("single", "overlap")]

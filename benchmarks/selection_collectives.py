@@ -168,6 +168,7 @@ for k in (1, 2, 4, 8):
     out[str(k)] = {"bytes_per_device": max(per_dev.values()),
                    "wall_ms": (time.perf_counter() - t0) / reps * 1e3,
                    "n_selected": int(res["n_selected"])}
+out["platform"] = jax.devices()[0].platform
 print(json.dumps(out))
 """
 
@@ -184,13 +185,15 @@ def bench_prefix_sharding() -> List[str]:
         raise RuntimeError(
             f"prefix_sharding child failed:\n{proc.stderr[-2000:]}")
     data = json.loads(proc.stdout.strip().splitlines()[-1])
+    platform = data.pop("platform")
     rows = []
     for k, d in sorted(data.items(), key=lambda kv: int(kv[0])):
         rows.append(f"prefix_clientaxis_bytes_per_device_k{k},"
                     f"{d['bytes_per_device']:.3e},"
                     f"N=256;64 probe samples/client")
         rows.append(f"prefix_wall_ms_k{k},{d['wall_ms']:.1f},"
-                    f"sharded selection prefix, {k} emulated devices")
+                    f"sharded selection prefix, {k} emulated devices;"
+                    f"platform={platform}")
     shrink = (data["1"]["bytes_per_device"]
               / max(data["8"]["bytes_per_device"], 1))
     if shrink < 4.0:                     # exact split measures 8.0
@@ -212,7 +215,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import elect as celect
 from repro.kernels import ref as kref
 from repro.launch import hlo_cost
@@ -239,7 +241,7 @@ def windowed_fn(n, road, window, cap):
             road_length=road, window=window, capacity=cap)
         return mask, jax.lax.pmax(ovf, CLIENT_AXIS)
 
-    return jax.jit(shard_map(f, mesh=mesh,
+    return jax.jit(jax.shard_map(f, mesh=mesh,
                              in_specs=(P(CLIENT_AXIS),) * 4,
                              out_specs=(P(CLIENT_AXIS), P())))
 
@@ -257,7 +259,7 @@ def gather_bytes_fn(n):
         merged = pg + eg                 # consume both gathers
         return jax.lax.dynamic_slice_in_dim(merged, i * shard_n, shard_n)
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=(P(CLIENT_AXIS),) * 2,
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(CLIENT_AXIS),) * 2,
                              out_specs=P(CLIENT_AXIS)))
 
 
@@ -274,7 +276,7 @@ def gather_elect_fn(n):
         i = jax.lax.axis_index(CLIENT_AXIS)
         return jax.lax.dynamic_slice_in_dim(mask, i * shard_n, shard_n)
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=(P(CLIENT_AXIS),) * 2,
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(CLIENT_AXIS),) * 2,
                              out_specs=P(CLIENT_AXIS)))
 
 
@@ -326,6 +328,7 @@ for n in NS:
                              "with overflow=0" % n)
         rec["parity_checked"] = int(rec["overflow"] == 0)
     out[str(n)] = rec
+out["platform"] = jax.devices()[0].platform
 print(json.dumps(out))
 """
 
@@ -359,6 +362,7 @@ def bench_windowed_scaling() -> List[str]:
             f"windowed_scaling child failed:\n{proc.stderr[-2000:]}\n"
             f"{proc.stdout[-500:]}")
     data = json.loads(proc.stdout.strip().splitlines()[-1])
+    platform = data.pop("platform")
     rows, cells = [], []
     halo, executed = {}, []
     for n_s, rec in sorted(data.items(), key=lambda kv: int(kv[0])):
@@ -379,11 +383,12 @@ def bench_windowed_scaling() -> List[str]:
             rows.append(f"windowed_elect_wall_ms_n{n},"
                         f"{rec['windowed_wall_ms']:.1f},"
                         f"16 emulated devices; overflow="
-                        f"{rec['overflow']}")
+                        f"{rec['overflow']};platform={platform}")
         if "gather_wall_ms" in rec:
             rows.append(f"gather_elect_wall_ms_n{n},"
                         f"{rec['gather_wall_ms']:.1f},"
-                        f"dense O(N^2) election on gathered vectors")
+                        f"dense O(N^2) election on gathered vectors;"
+                        f"platform={platform}")
         cells.append({"n": n, **rec})
     # gate 1: halo bytes flat in N at fixed density (the whole point —
     # the exchanged window is determined by density, not fleet size)

@@ -152,10 +152,11 @@ def sorted_window_counts(sp: jax.Array, se: jax.Array, sg: jax.Array, *,
     sgp = jnp.pad(sg, (0, pad), constant_values=n_valid)
     if impl == "pallas":
         from repro.kernels.neighbor_elect import windowed_counts_pallas
+        from repro.kernels.ops import pallas_interpret
         counts = windowed_counts_pallas(
             spp, sep, sgp, comm_range=comm_range, e_tau=e_tau,
             n_valid=n_valid, window=w, block=b,
-            interpret=jax.default_backend() != "tpu")[:m]
+            interpret=pallas_interpret())[:m]
     else:
         counts = _counts_block_jnp(spp, sep, sgp, comm_range=comm_range,
                                    e_tau=e_tau, n_valid=n_valid, window=w,

@@ -26,13 +26,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.fuzzy import FuzzyEvaluator
 
 
 def _shmap(f, mesh, in_specs, out_specs):
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def _elect_block(pos_i, ev_i, idx_i, pos_all, ev_all, idx_all, *,

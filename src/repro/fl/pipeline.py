@@ -52,7 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.rules import build_rule_table
 from repro.core.selection import selection_stats
 from repro.fl.aggregation import fedavg_masked, fedavg_sums
@@ -125,7 +124,8 @@ class StageConfig:
     # device-resident fused probe->evaluate fast path (kops.probe_fuzzy):
     # default OFF — the staged jnp path below stays the bitwise-pinned
     # reference.  ON, the Eq. 7 probe forward, Eq. 8 normalization and
-    # Mamdani inference run as one fused op (one Pallas launch on TPU),
+    # Mamdani inference run as one fused op (one Pallas launch under
+    # REPRO_KERNEL_IMPL=pallas, one XLA program under the default jnp),
     # and the simulation packs the probe TIGHT (no per-client batch
     # alignment), so small clients stop paying dead probe rows.  Masks
     # are pinned bit-identical to the unfused path in
@@ -510,8 +510,8 @@ def _sharded_prefix_fn(cfg: StageConfig, mesh: Mesh, seeds: bool):
         # Eq. 7 over the local probe shard; every client's samples live
         # on its owner device, so the psum adds exact zeros elsewhere.
         # The fused fast path swaps in the fused probe op (one Pallas
-        # launch per shard on TPU; the psum seam below and the Eq. 8
-        # pmax stay outside the kernel by design).
+        # launch per shard under the pallas impl; the psum seam below
+        # and the Eq. 8 pmax stay outside the kernel by design).
         if cfg.fused_probe:
             lf_part = kops.probe_loss(params, pim, plb, pseg, counts,
                                       n_clients=n, batch=cfg.probe_batch)
@@ -628,8 +628,8 @@ def _sharded_prefix_fn(cfg: StageConfig, mesh: Mesh, seeds: bool):
     body = core if not seeds else jax.vmap(
         core, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
                        None, 0, None, 0, 0))
-    sharded = shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
 
     def run(st: RoundStatics, params: Params, rnd: jax.Array,
             sel_key: jax.Array, net_key: jax.Array):
@@ -743,8 +743,8 @@ def _sharded_group_trainer(mesh: Mesh, epochs: int, batch_size: int,
         return fedavg_sums(stacked, w, axis_name=CLIENT_AXIS)
 
     c = P(CLIENT_AXIS)
-    sharded = shard_map(body, mesh, in_specs=(P(), c, c, c, c, c),
-                        out_specs=(P(), P()), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(), c, c, c, c, c),
+                            out_specs=(P(), P()), check_vma=False)
     # the cohort shards are device_put fresh per round by the gather
     # below — donate them so the per-device training buffers recycle
     return jax.jit(sharded, donate_argnums=(1, 2, 3, 4, 5))

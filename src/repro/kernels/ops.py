@@ -1,10 +1,17 @@
 """Jit'd dispatch wrappers over the Pallas kernels and their jnp paths.
 
 Selection order (env ``REPRO_KERNEL_IMPL`` or the ``impl=`` argument):
-- ``jnp``     : fast pure-jnp implementation (default on CPU — this
-                container); identical math to the oracle, chunked/vmapped.
-- ``pallas``  : Pallas kernel, ``interpret=True`` unless on a real TPU.
+- ``jnp``     : pure-jnp implementation, the default on every backend
+                (the TPU included); identical math to the oracle,
+                chunked/vmapped, compiled by XLA.
+- ``pallas``  : the Pallas kernel — compiled for the chip on a TPU,
+                interpret mode on the CPU, refused on any other backend
+                (``pallas_interpret``).
 - ``oracle``  : the naive reference from ``ref.py`` (tests only).
+
+The env var is read while a caller traces, and jit caches are not keyed
+on it: a process that changes ``REPRO_KERNEL_IMPL`` after its first
+round must ``jax.clear_caches()`` before the new impl takes effect.
 """
 from __future__ import annotations
 
@@ -22,8 +29,18 @@ def _impl(arg: Optional[str]) -> str:
     return arg or os.environ.get("REPRO_KERNEL_IMPL", "jnp")
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def pallas_interpret() -> bool:
+    """The ``interpret=`` flag of every Pallas call: the kernels compile
+    for the chip on a TPU and run in interpret mode on the CPU (tests).
+    Any other backend raises rather than silently interpreting."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels target the TPU (interpret mode on the CPU); "
+        f"backend {backend!r} has neither — use the jnp impl")
 
 
 # --------------------------------------------------------------------------
@@ -35,7 +52,7 @@ def wkv6(r, k, v, w, u, s0, impl: Optional[str] = None
     m = _impl(impl)
     if m == "pallas":
         from repro.kernels.wkv6 import wkv6_pallas
-        return wkv6_pallas(r, k, v, w, u, s0, interpret=_interpret())
+        return wkv6_pallas(r, k, v, w, u, s0, interpret=pallas_interpret())
     if m == "oracle":
         return kref.wkv6_ref(r, k, v, w, u, s0)
     if m == "scan":
@@ -76,7 +93,7 @@ def fuzzy_eval(x, means, sigmas, rule_table: np.ndarray,
     if m == "pallas":
         from repro.kernels.fuzzy_eval import fuzzy_eval_pallas
         return fuzzy_eval_pallas(x, means, sigmas, rule_table, rule_levels,
-                                 level_centers, interpret=_interpret(),
+                                 level_centers, interpret=pallas_interpret(),
                                  normalize=normalize)
     return kref.fuzzy_eval_ref(x, means, sigmas, rule_table, rule_levels,
                                level_centers, normalize=normalize)
@@ -94,14 +111,14 @@ def probe_fuzzy(params, images, labels, seg, counts, aux, means, sigmas,
     """The selection prefix's device-resident fast path: packed Eq. 7
     probe samples -> per-client raw features + Mamdani evaluations.
 
-    - ``jnp`` (default on CPU): the chunked packed probe
+    - ``jnp`` (default): the chunked packed probe
       (``dataset_loss_packed``) and the reference Mamdani inference fused
       into the caller's jit — one XLA program, no intermediate host or
       HBM round-trips between the stages.
     - ``pallas``: ONE kernel launch (``probe_fuzzy_pallas``): the conv/
       pool/dense probe staged through VMEM, per-client one-hot loss
       reduction on the lane axis, Eq. 8 + 81-rule Mamdani on the final
-      grid step.  Interpret mode off-TPU.
+      grid step.  Interpret mode on the CPU.
     - ``oracle``: the naive unchunked transcription (tests only).
 
     ``aux``: (N, 3) raw [SQ, TA, CC] columns; ``col_maxima``: optional
@@ -113,7 +130,7 @@ def probe_fuzzy(params, images, labels, seg, counts, aux, means, sigmas,
         return probe_fuzzy_pallas(params, images, labels, seg, counts, aux,
                                   means, sigmas, rule_table, rule_levels,
                                   level_centers, n_clients=n_clients,
-                                  interpret=_interpret(),
+                                  interpret=pallas_interpret(),
                                   col_maxima=col_maxima)
     if m == "oracle":
         return kref.probe_fuzzy_ref(params, images, labels, seg, counts,
@@ -141,7 +158,7 @@ def probe_loss(params, images, labels, seg, counts, *, n_clients: int,
         from repro.kernels.probe_fuzzy import probe_loss_pallas
         return probe_loss_pallas(params, images, labels, seg, counts,
                                  n_clients=n_clients,
-                                 interpret=_interpret())
+                                 interpret=pallas_interpret())
     if m == "oracle":
         return kref.probe_loss_ref(params, images, labels, seg, counts,
                                    n_clients=n_clients)
@@ -161,7 +178,7 @@ def neighbor_elect(pos, evals, *, comm_range: float, top_m: int,
         from repro.kernels.neighbor_elect import neighbor_elect_pallas
         return neighbor_elect_pallas(pos, evals, comm_range=comm_range,
                                      top_m=top_m, e_tau=e_tau,
-                                     interpret=_interpret())
+                                     interpret=pallas_interpret())
     return kref.neighbor_elect_ref(pos, evals, comm_range=comm_range,
                                    top_m=top_m, e_tau=e_tau)
 
@@ -197,7 +214,7 @@ def selective_scan(x, dt, bmat, cmat, a, h0, impl: Optional[str] = None
     if m == "pallas":
         from repro.kernels.selective_scan import selective_scan_pallas
         return selective_scan_pallas(x, dt, bmat, cmat, a, h0,
-                                     interpret=_interpret())
+                                     interpret=pallas_interpret())
     return kref.selective_scan_ref(x, dt, bmat, cmat, a, h0)
 
 
@@ -215,7 +232,7 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, causal=True, window=0,
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       prefix_len=prefix_len,
-                                      interpret=_interpret())
+                                      interpret=pallas_interpret())
     from repro.models.attention import flash_attention as flash_jnp
     return flash_jnp(q, k, v, q_pos, kv_pos, causal=causal, window=window,
                      prefix_len=prefix_len)

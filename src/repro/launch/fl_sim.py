@@ -51,6 +51,22 @@ def paper_config(scheme: str, **kw):
                        deadline_s=20.0, **kw)
 
 
+def sim_config(scheme: str, *, paper_profile: bool, rounds: int,
+               classes_per_client: int = 9, seed: int = 0,
+               distribution: str = "uniform"):
+    """The ``FLSimConfig`` one ``fl_sim`` launch simulates for
+    ``scheme``: the Table 3 profile or the CPU-budget one, on the
+    freeway mobility field of ``seed``."""
+    from repro.fl.mobility import MobilityConfig
+    if paper_profile:
+        cfg = paper_config(scheme, seed=seed)
+    else:
+        cfg = fast_config(scheme, n_rounds=rounds,
+                          classes_per_client=classes_per_client, seed=seed)
+    cfg.mobility = MobilityConfig(distribution=distribution, seed=seed)
+    return cfg
+
+
 def main(argv=None) -> int:
     # argparse only below — jax must not initialize before the mesh
     # context can force emulated host devices
@@ -91,13 +107,11 @@ def main(argv=None) -> int:
     with client_mesh_context(args.mesh,
                              multihost=multihost_from_args(args)) as mesh:
         import jax
-        from repro.fl.mobility import MobilityConfig
         from repro.fl.rounds import FLSimulation
         from repro.fl.runconfig import RunConfig
         from repro.launch.cache import enable_jit_cache
         is_lead = jax.process_index() == 0
-        enable_jit_cache(resolve_cache_dir(args.jit_cache_dir,
-                                           args.out or "fl_sim.json"))
+        enable_jit_cache(resolve_cache_dir(args.jit_cache_dir))
         if mesh is not None and is_lead:
             print(f"[fl_sim] client mesh: {dict(mesh.shape)} over "
                   f"{mesh.devices.size} devices"
@@ -113,13 +127,10 @@ def main(argv=None) -> int:
         schemes = SCHEMES if args.scheme == "all" else (args.scheme,)
         results = {}
         for scheme in schemes:
-            mk = paper_config if args.paper_profile else fast_config
-            cfg = mk(scheme, n_rounds=args.rounds,
-                     classes_per_client=args.classes_per_client,
-                     seed=args.seed) \
-                if not args.paper_profile else mk(scheme, seed=args.seed)
-            cfg.mobility = MobilityConfig(distribution=args.distribution,
-                                          seed=args.seed)
+            cfg = sim_config(scheme, paper_profile=args.paper_profile,
+                             rounds=args.rounds,
+                             classes_per_client=args.classes_per_client,
+                             seed=args.seed, distribution=args.distribution)
             srun = run
             if run.checkpoint_dir:
                 # one snapshot directory per scheme, so --scheme all

@@ -78,16 +78,10 @@ def init_distributed(coordinator: str, num_processes: int,
     attempts = int(os.environ.get("REPRO_DIST_INIT_ATTEMPTS", "3"))
 
     def _init():
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=num_processes, process_id=process_id,
-                initialization_timeout=timeout_s)
-        except TypeError:
-            # older jax without the kwarg: fall back to its default
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=num_processes, process_id=process_id)
+        jax.distributed.initialize(
+            coordinator_address=coordinator,
+            num_processes=num_processes, process_id=process_id,
+            initialization_timeout=timeout_s)
 
     retry_with_backoff(
         _init, attempts=attempts,

@@ -1,10 +1,11 @@
 """Multi-process launch plumbing (jax-free: argparse + subprocess only).
 
 ``--multihost P`` runs a launcher as ``P`` cooperating jax processes —
-on CPU this *emulates* a multi-host fleet by spawning ``P`` copies of
-the same command wired to one local coordinator, each owning
-``K / P`` of the ``clients`` mesh devices; on a real multi-host slice
-the same flags describe the actual coordinator/process topology.
+a CPU *emulation* of a multi-host fleet: ``P`` copies of the same
+command wired to one local coordinator, each owning ``K / P`` emulated
+host devices of the ``clients`` mesh, with gloo CPU collectives.  On
+any other backend the launch is refused (``require_cpu_backend``): a
+chip belongs to one process, so ``P`` local processes cannot share it.
 
 The spawn protocol is self-re-execution: the parent parses
 ``--multihost P``, picks a free coordinator port, and re-launches its
@@ -52,8 +53,8 @@ def retry_with_backoff(fn: Callable, *, attempts: int = 3,
 def add_multihost_arguments(ap) -> None:
     """Install ``--multihost`` plus the hidden child-process flags."""
     ap.add_argument("--multihost", type=int, default=0, metavar="P",
-                    help="run as P cooperating jax processes (CPU: "
-                         "emulated via spawned local processes); the "
+                    help="run as P cooperating jax processes (a CPU "
+                         "emulation via spawned local processes); the "
                          "mesh's clients=K axis spans all of them "
                          "(K %% P == 0)")
     ap.add_argument("--_mh-coord", default=None, help=_SUPPRESS())
@@ -82,6 +83,20 @@ def should_spawn(args) -> bool:
     and not already a spawned child)."""
     return (getattr(args, "multihost", 0) or 0) > 1 \
         and getattr(args, "_mh_proc_id", None) is None
+
+
+def require_cpu_backend(what: str) -> None:
+    """Refuse a launch mode that starts several jax processes on this
+    host unless the backend is the CPU.  A TPU chip belongs to one
+    process at a time: a parent holding it leaves its children failing
+    or hanging, and sibling children cannot share it either."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"{what} starts several jax processes on this host, but the "
+            f"{backend} backend gives each chip to one process; run "
+            f"without it (one process drives every local chip)")
 
 
 def free_port() -> int:
@@ -119,6 +134,7 @@ def spawn_multihost(module: str, argv: Sequence[str], nprocs: int,
     otherwise leave the rest blocked in a collective forever) and the
     error names the dead rank.  ``timeout`` bounds the whole launch the
     same way (exit code 124, like timeout(1))."""
+    require_cpu_backend(f"--multihost {nprocs}")
     coord = f"127.0.0.1:{free_port()}"
     procs: List[subprocess.Popen] = []
     for pid in range(nprocs):

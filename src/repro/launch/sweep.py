@@ -630,7 +630,8 @@ def main(argv=None) -> int:
     ap.add_argument("--paper-profile", action="store_true",
                     help="Table 3 profile (expensive on CPU)")
     ap.add_argument("--workers", type=int, default=1,
-                    help="worker processes for cell groups (1 = in-process)")
+                    help="worker processes for cell groups (1 = "
+                         "in-process; more only on the CPU backend)")
     ap.add_argument("--no-vmap", action="store_true",
                     help="disable the seed-vmapped selection prefix")
     # the shared RunConfig flags (mesh / fused probe / overlap / server /
@@ -648,7 +649,8 @@ def main(argv=None) -> int:
                          "seconds (scenario axis; 0 = the round period)")
     from repro.launch.cache import add_cache_arguments, resolve_cache_dir
     from repro.launch.multihost import (add_multihost_arguments,
-                                        multihost_from_args, should_spawn,
+                                        multihost_from_args,
+                                        require_cpu_backend, should_spawn,
                                         spawn_multihost)
     add_multihost_arguments(ap)
     add_cache_arguments(ap)
@@ -700,13 +702,15 @@ def main(argv=None) -> int:
                              or (base_run.agg_cadence_s or 0.0,))
 
     t0 = time.time()
-    cache_dir = resolve_cache_dir(args.jit_cache_dir, args.out)
+    cache_dir = resolve_cache_dir(args.jit_cache_dir)
     from repro.launch.cache import enable_jit_cache
     from repro.launch.mesh import client_mesh_context
     with client_mesh_context(args.mesh,
                              multihost=multihost_from_args(args)) as mesh:
         is_lead = jax.process_index() == 0
-        if args.workers <= 1:
+        if args.workers > 1:
+            require_cpu_backend(f"--workers {args.workers}")
+        else:
             enable_jit_cache(cache_dir)   # workers enable their own
         if mesh is not None and is_lead:
             print(f"[sweep] client mesh: {dict(mesh.shape)} over "

@@ -13,7 +13,6 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.layers import Params, dense_init, PARAM_DTYPE
 from repro.sharding.api import constrain
 
@@ -194,7 +193,7 @@ def _apply_moe_ep(cfg, p: Params, x: jax.Array, mesh
 
     xspec = P(batch_axes if batch_axes else None, None, None)
     wspec = P("model", "data" if data_ax else None, None)
-    y, lb, z, assign = shard_map(
+    y, lb, z, assign = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, None), wspec, wspec, wspec),
         out_specs=(xspec, P(), P(), P()),

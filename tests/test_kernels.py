@@ -229,3 +229,15 @@ def test_flash_pallas_vs_jnp(sq, skv, hq, hkv, dh, causal, window, prefix,
     np.testing.assert_allclose(np.asarray(out_p, np.float32),
                                np.asarray(out_j, np.float32),
                                atol=tol, rtol=tol)
+
+
+def test_pallas_interpret_only_on_cpu(monkeypatch):
+    """Interpret mode is the CPU's; the TPU compiles; any other backend
+    refuses the Pallas impl instead of silently interpreting."""
+    from repro.kernels import ops
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops.pallas_interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="backend 'gpu'"):
+        ops.pallas_interpret()

@@ -80,11 +80,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 mesh = make_multihost_clients_mesh(4)
 x = np.arange(8, dtype=np.float32)
 xs = jax.device_put(x, NamedSharding(mesh, P("clients")))
-tot = jax.jit(shard_map(
+tot = jax.jit(jax.shard_map(
     lambda v: jax.lax.psum(v.sum(), "clients"),
     mesh=mesh, in_specs=P("clients"), out_specs=P()))(xs)
 assert float(jax.device_get(tot)) == float(x.sum()), tot
@@ -183,3 +182,19 @@ def test_fl_sim_multihost_launch(multihost_available, tmp_path):
     data = json.loads(out.read_text())
     assert "dcs" in data and len(data["dcs"]) == 1
     assert "2 processes" in proc.stdout
+
+
+def test_multi_process_modes_refuse_off_cpu(monkeypatch, tmp_path):
+    """``--multihost`` and ``sweep --workers > 1`` start several jax
+    processes on one host; off the CPU backend (a chip belongs to one
+    process) both refuse before starting any."""
+    import jax
+
+    from repro.launch import multihost, sweep
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="gives each chip to one process"):
+        multihost.spawn_multihost("repro.launch.fl_sim", [], 2)
+    with pytest.raises(SystemExit, match="--workers 2"):
+        sweep.main(["--workers", "2", "--schemes", "dcs", "--seeds", "1",
+                    "--rounds", "1", "--out", str(tmp_path / "s.csv")])
+    assert not list(tmp_path.iterdir())

@@ -237,19 +237,30 @@ def cfg(scheme, seed=0, **kw):
         mobility=MobilityConfig(n_vehicles=N, seed=seed), **kw)
 
 def run_case(scheme, k, rounds):
+    # ``fused`` and ``sh`` carry their own params from round to round;
+    # ``step`` (sharded) starts every round from the plain sim's params.
+    # The sharded trainer aggregates in another float order (per-shard
+    # sums, then a psum), and a later local SGD round amplifies those few
+    # ulps through ReLU and max-pool switches, so the carried sharded sim is
+    # held to the masks, survivors and evaluations after round 0, and
+    # ``step`` to the round-0 accuracy bound in every round.
     plain = FLSimulation(cfg(scheme),                 # unfused, unsharded
                          run=RunConfig(fused_probe=False))
     fused = FLSimulation(cfg(scheme))                 # fused default
     mesh = make_clients_mesh(k)
     with mesh, logical_sharding(mesh, DEFAULT_RULES):
         sh = FLSimulation(cfg(scheme))
+        step = FLSimulation(cfg(scheme))
         assert sh.client_mesh is not None and sh.n_shards == k
         n_sel = 0
         for r in range(rounds):
+            step.params = plain.params
             a = jax.device_get(plain.selection_state(r))
             b = jax.device_get(fused.selection_state(r))
             c = jax.device_get(sh.selection_state(r))
-            for tag, s in (("fused", b), ("fused+sharded", c)):
+            d = jax.device_get(step.selection_state(r))
+            for tag, s in (("fused", b), ("fused+sharded", c),
+                           ("fused+sharded step", d)):
                 np.testing.assert_array_equal(
                     np.asarray(a["mask"]), np.asarray(s["mask"]),
                     err_msg=f"{scheme} k={k} round {r}: {tag} mask")
@@ -261,8 +272,11 @@ def run_case(scheme, k, rounds):
             ra = plain.finish_round(r, a)
             rb = fused.finish_round(r, b)
             rc = sh.finish_round(r, c)
+            rd = step.finish_round(r, d)
             assert abs(ra["accuracy"] - rb["accuracy"]) <= 1e-5
-            assert abs(ra["accuracy"] - rc["accuracy"]) <= 1e-5
+            assert abs(ra["accuracy"] - rd["accuracy"]) <= 1e-5
+            if r == 0:
+                assert abs(ra["accuracy"] - rc["accuracy"]) <= 1e-5
             n_sel += int(np.asarray(c["mask"]).sum())
         return n_sel
 

@@ -26,7 +26,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.fl import pipeline
 from repro.fl.aggregation import fedavg_masked, fedavg_sums
 from repro.launch.mesh import (client_mesh_context, make_clients_mesh,
@@ -64,34 +63,55 @@ def cfg(scheme, seed=0, **kw):
 def leaves(p):
     return [np.asarray(x) for x in jax.tree.leaves(p)]
 
+def max_ulps(p, q):
+    # the largest gap of each leaf in ulps of the leaf's largest magnitude
+    return max(float(np.max(np.abs(a - b)) / np.spacing(np.max(np.abs(a))))
+               for a, b in zip(leaves(p), leaves(q)))
+
 def run_case(scheme, k, rounds, **kw):
+    # ``sh`` carries its own params from round to round; ``step`` starts
+    # every round from the reference's params, so it compares one sharded
+    # round with one plain round.  The two aggregate in another float order
+    # (per-shard sums, then a psum), which costs a few ulps (checked at
+    # round 0); a later local SGD round amplifies such ulps through ReLU and
+    # max-pool switches, so the carried params are held to the same masks,
+    # survivors and evaluations, and ``step`` to the 1e-5 params bound.
     ref = FLSimulation(cfg(scheme, **kw))
     mesh = make_clients_mesh(k)
     with mesh, logical_sharding(mesh, DEFAULT_RULES):
         assert len(sweep_devices()) == 1        # one placement domain
         sh = FLSimulation(cfg(scheme, **kw))
+        step = FLSimulation(cfg(scheme, **kw))
         assert sh.client_mesh is not None and sh.n_shards == k
         n_sel = 0
         for r in range(rounds):
+            step.params = ref.params
             a = jax.device_get(ref.selection_state(r))
-            b = jax.device_get(sh.selection_state(r))
-            np.testing.assert_array_equal(
-                np.asarray(a["mask"]), np.asarray(b["mask"]),
-                err_msg=f"{scheme} k={k} round {r}: masks diverge")
-            np.testing.assert_array_equal(np.asarray(a["survivors"]),
-                                          np.asarray(b["survivors"]))
-            np.testing.assert_allclose(np.asarray(a["evals"]),
-                                       np.asarray(b["evals"]),
-                                       rtol=1e-4, atol=1e-3)
-            assert int(a["n_straggler"]) == int(b["n_straggler"])
-            assert int(a["n_selected"]) == int(b["n_selected"])
+            for sim, tag in ((sh, "carried"), (step, "step")):
+                b = jax.device_get(sim.selection_state(r))
+                np.testing.assert_array_equal(
+                    np.asarray(a["mask"]), np.asarray(b["mask"]),
+                    err_msg=f"{scheme} k={k} round {r} {tag}: masks diverge")
+                np.testing.assert_array_equal(np.asarray(a["survivors"]),
+                                              np.asarray(b["survivors"]))
+                np.testing.assert_allclose(np.asarray(a["evals"]),
+                                           np.asarray(b["evals"]),
+                                           rtol=1e-4, atol=1e-3)
+                assert int(a["n_straggler"]) == int(b["n_straggler"])
+                assert int(a["n_selected"]) == int(b["n_selected"])
+                if sim is sh:
+                    rb = sh.finish_round(r, b)
+                else:
+                    rs = step.finish_round(r, b)
             ra = ref.finish_round(r, a)
-            rb = sh.finish_round(r, b)
-            for la, lb in zip(leaves(ref.params), leaves(sh.params)):
+            for la, lb in zip(leaves(ref.params), leaves(step.params)):
                 np.testing.assert_allclose(
                     la, lb, atol=1e-5,
                     err_msg=f"{scheme} k={k} round {r}: params diverge")
-            assert abs(ra["accuracy"] - rb["accuracy"]) <= 1e-5
+            assert abs(ra["accuracy"] - rs["accuracy"]) <= 1e-5
+            if r == 0:
+                assert max_ulps(ref.params, sh.params) <= 4
+                assert abs(ra["accuracy"] - rb["accuracy"]) <= 1e-5
             n_sel += int(b["n_selected"])
         return n_sel
 
@@ -247,10 +267,10 @@ def test_fedavg_masked_axis_name_matches_unsharded():
                "b": jnp.asarray(rng.normal(size=(4,)).astype(np.float32))}
     weights = jnp.asarray([120.0, 40.0, 0.0, 40.0])
     mesh = _mesh1()
-    sharded = shard_map(
-        lambda s, w: fedavg_masked(s, w, axis_name="clients"), mesh,
+    sharded = jax.shard_map(
+        lambda s, w: fedavg_masked(s, w, axis_name="clients"), mesh=mesh,
         in_specs=(P("clients"), P("clients")), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     got = sharded(stacked, weights)
     want = fedavg_masked(stacked, weights)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):

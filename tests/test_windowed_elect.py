@@ -199,30 +199,42 @@ def _min_cfg():
     return FLSimConfig(scheme="dcs")
 
 
-def test_resolve_cache_dir_default_and_disable():
-    assert resolve_cache_dir(None, "/tmp/x/out.json") == "/tmp/x/.jit-cache"
-    assert resolve_cache_dir("none", "/tmp/x/out.json") is None
-    assert resolve_cache_dir("", "/tmp/x/out.json") is None
-    assert resolve_cache_dir("/d", "/tmp/x/out.json") == "/d"
+def test_resolve_cache_dir_default_and_disable(monkeypatch, tmp_path):
+    """Flag > ``JAX_COMPILATION_CACHE_DIR`` > the fixed checkout-root
+    default, which depends on neither the cwd nor an output path."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    assert resolve_cache_dir(None) == str(REPO / ".jit-cache")
+    assert resolve_cache_dir("none") is None
+    assert resolve_cache_dir("") is None
+    assert resolve_cache_dir("/d") == "/d"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/env")
+    assert resolve_cache_dir(None) == "/env"
+    assert resolve_cache_dir("/d") == "/d"
+    assert resolve_cache_dir("none") is None
 
 
 def test_jit_cache_populates(tmp_path):
-    """enable_jit_cache must actually persist CPU executables (the
-    default thresholds would skip them) — run a tiny jit in a subprocess
-    and check the directory gained entries."""
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, the launcher's cache
+    wiring persists CPU executables there (the default thresholds would
+    skip them) and points jax at no other directory — run a tiny jit in
+    a subprocess and check that directory gained entries."""
     cache = tmp_path / "jc"
     child = (
-        "from repro.launch.cache import enable_jit_cache\n"
-        f"enable_jit_cache({str(cache)!r})\n"
+        "from repro.launch.cache import enable_jit_cache, "
+        "resolve_cache_dir\n"
+        "print(enable_jit_cache(resolve_cache_dir(None)))\n"
         "import jax, jax.numpy as jnp\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
         "print(int(jax.jit(lambda x: (x * 3 + 1).sum())"
         "(jnp.arange(128.0))))\n")
     env = {**os.environ, "PYTHONPATH": str(REPO / "src"),
-           "JAX_PLATFORMS": "cpu"}
+           "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(cache)}
     proc = subprocess.run([sys.executable, "-c", child],
                           capture_output=True, text=True, env=env,
-                          cwd=REPO, timeout=300)
+                          cwd=tmp_path, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == [str(cache), str(cache), "24512"]
     assert cache.is_dir() and any(cache.iterdir()), \
         "persistent jit cache stayed empty"
 
@@ -317,7 +329,6 @@ with mesh, logical_sharding(mesh, DEFAULT_RULES):
     import jax.numpy as jnp
     from repro.core.elect import (auto_capacity, auto_window,
                                   ring_halo_elect)
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels.ref import neighbor_elect_ref
     n, k, road = 64, 8, 400.0
@@ -333,7 +344,7 @@ with mesh, logical_sharding(mesh, DEFAULT_RULES):
                 road_length=road, window=auto_window(n, 120.0, road),
                 capacity=auto_capacity(n // k, k))
             return m_, jax.lax.pmax(o_, "clients")
-        fn = shard_map(body, mesh=mesh, in_specs=(P("clients"),) * 4,
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(P("clients"),) * 4,
                        out_specs=(P("clients"), P()))
         mask, ovf = fn(jnp.asarray(pos), jnp.asarray(ev),
                        jnp.arange(n, dtype=jnp.int32),
