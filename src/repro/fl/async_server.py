@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl import pipeline
+from repro.fl import pipeline, trace
 from repro.fl.aggregation import fedavg_masked
 from repro.fl.client import evaluate_accuracy_async
 from repro.fl.rounds import (build_round_checkpointer, checkpoint_round,
@@ -143,10 +143,11 @@ class EventDrivenServer:
         if self.sync_equivalent:
             self.sim._dispatch_training(rnd, host)
             return
-        self._stats[rnd] = {"n_agg": 0, "n_stale": 0, "eff": 0.0,
-                            "hist": [0] * _HIST_BINS}
-        self._enqueue_round(rnd, host)
-        self._process_due_ticks(rnd)
+        with trace.span(trace.COHORT, round=rnd):
+            self._stats[rnd] = {"n_agg": 0, "n_stale": 0, "eff": 0.0,
+                                "hist": [0] * _HIST_BINS}
+            self._enqueue_round(rnd, host)
+            self._process_due_ticks(rnd)
 
     def _enqueue_round(self, rnd: int, host: Dict) -> None:
         sim = self.sim
@@ -180,7 +181,8 @@ class EventDrivenServer:
                     sim.params, sim.groups, sim._group_steps, bucket,
                     keys, sim.client_mesh, epochs=cfg.local_epochs,
                     batch_size=cfg.batch_size, lr=cfg.lr,
-                    prox_mu=cfg.prox_mu, weight_scale=float(s))
+                    prox_mu=cfg.prox_mu, weight_scale=float(s),
+                    counters=sim.counters)
                 if trained is None:
                     continue
                 num, den = trained
@@ -194,7 +196,8 @@ class EventDrivenServer:
         entries = pipeline.train_groups(
             sim.params, sim.groups, sim._group_steps, train_mask, keys,
             epochs=cfg.local_epochs, batch_size=cfg.batch_size,
-            lr=cfg.lr, prox_mu=cfg.prox_mu, return_entries=True)
+            lr=cfg.lr, prox_mu=cfg.prox_mu, return_entries=True,
+            counters=sim.counters)
         if entries is None:
             return
         merged, w, row_ids = entries
@@ -327,11 +330,12 @@ class EventDrivenServer:
                      state: Dict[str, jax.Array]) -> Dict[str, float]:
         """Complete round ``rnd`` from a selection-prefix output (the
         sweep harness's per-seed entry point)."""
-        host = self.sim.resolve_elect_overflow(rnd, jax.device_get(state))
+        host = self.sim.gather_selection(rnd, state)
         self._dispatch_training(rnd, host)
-        acc, n_test = evaluate_accuracy_async(
-            self.sim.params, self.sim.test_images, self.sim.test_labels,
-            batch=256)
+        with trace.span(trace.DISPATCH, round=rnd):
+            acc, n_test = evaluate_accuracy_async(
+                self.sim.params, self.sim.test_images,
+                self.sim.test_labels, batch=256)
         return self._round_row(rnd, host, acc, n_test)
 
     # -- drivers ---------------------------------------------------------
@@ -354,20 +358,26 @@ class EventDrivenServer:
             overlap = self.run_cfg.overlap_rounds
         if not overlap:
             for r in range(start, n):
-                rows.append(self.finish_round(r, sim.selection_state(r)))
-                checkpoint_round(self, ckpt, r, rows)
+                with trace.round_span(r):
+                    rows.append(self.finish_round(r,
+                                                  sim.selection_state(r)))
+                    checkpoint_round(self, ckpt, r, rows)
             return rows
         if start >= n:
             return rows
         state = sim.selection_state(start)
         for r in range(start, n):
-            host = jax.device_get(state)     # fence: the cohort gather
-            host = sim.resolve_elect_overflow(r, host)
-            self._dispatch_training(r, host)
-            acc, n_test = evaluate_accuracy_async(
-                sim.params, sim.test_images, sim.test_labels, batch=256)
-            if r + 1 < n:                    # round-ahead: r+1's prefix
-                state = sim.selection_state(r + 1)
-            rows.append(self._round_row(r, host, acc, n_test))
-            checkpoint_round(self, ckpt, r, rows)
+            with trace.round_span(r):
+                host = sim.gather_selection(r, state)
+                self._dispatch_training(r, host)
+                ahead = r + 1 < n            # round-ahead: r+1's prefix
+                with trace.span(trace.DISPATCH, round=r,
+                                prefix_round=r + 1 if ahead else None):
+                    acc, n_test = evaluate_accuracy_async(
+                        sim.params, sim.test_images, sim.test_labels,
+                        batch=256)
+                    if ahead:
+                        state = sim.selection_state(r + 1)
+                rows.append(self._round_row(r, host, acc, n_test))
+                checkpoint_round(self, ckpt, r, rows)
         return rows

@@ -67,6 +67,7 @@ from repro.fl.network import (NetworkConfig, cwnd_loss_fields,
 from repro.fl.partition import ClientGroup
 from repro.fl.timing import (TimingConfig, completes_before_deadline,
                              training_time_s)
+from repro.fl.trace import RoundCounters
 from repro.kernels import ops as kops
 from repro.sharding.api import (CLIENT_AXIS, current_mesh, mesh_axis_size,
                                 mesh_is_multihost, resolve_pspec)
@@ -178,6 +179,12 @@ def features(st: RoundStatics, cfg: StageConfig, params: Params,
     per-column max-scaling is folded into the ``evaluate`` stage's
     kernel, so no normalization happens here."""
     pos = positions(st, cfg, t_s)
+    return pos, _raw_features(st, cfg, params, pos, net_key)
+
+
+def _raw_features(st: RoundStatics, cfg: StageConfig, params: Params,
+                 pos: jax.Array, net_key: jax.Array) -> jax.Array:
+    """``features`` at given positions: the raw (N, 4) columns."""
     sq_raw = st.n_valid
     ta_raw = predicted_throughput_jax(cfg.network, pos, net_key)
     cc_raw = 1.0 / st.slowdown
@@ -185,9 +192,8 @@ def features(st: RoundStatics, cfg: StageConfig, params: Params,
                                  st.probe_seg, st.probe_counts,
                                  n_clients=cfg.n_clients,
                                  batch=cfg.probe_batch)
-    feats = jnp.stack([sq_raw, ta_raw, cc_raw, lf_raw],
-                      axis=1).astype(jnp.float32)
-    return pos, feats
+    return jnp.stack([sq_raw, ta_raw, cc_raw, lf_raw],
+                     axis=1).astype(jnp.float32)
 
 
 def evaluate(st: RoundStatics, feats_raw: jax.Array) -> jax.Array:
@@ -238,60 +244,68 @@ def _prefix(st: RoundStatics, params: Params, rnd: jax.Array,
     t_s = rnd.astype(jnp.float32) * cfg.timing.deadline_s
     k_sel = jax.random.fold_in(sel_key, rnd)
     k_pred, k_upload = jax.random.split(jax.random.fold_in(net_key, rnd))
-    if cfg.fused_probe:
-        # fused fast path: probe forward + Eq. 8 + Mamdani as one op —
-        # a single kernel launch on the Pallas impl, one fused XLA
-        # subgraph on the jnp impl (plus the tight probe pack built by
-        # FLSimulation when the flag is on)
+    # the named scopes (trace.SCOPES) label each stage's ops in the
+    # compiled HLO's op_name, and so in a device trace
+    with jax.named_scope("positions"):
         pos = positions(st, cfg, t_s)
-        ta_raw = predicted_throughput_jax(cfg.network, pos, k_pred)
-        aux = jnp.stack([st.n_valid, ta_raw, 1.0 / st.slowdown],
-                        axis=1).astype(jnp.float32)
-        table, levels = _rules()
-        feats, evals = kops.probe_fuzzy(
-            params, st.probe_images, st.probe_labels, st.probe_seg,
-            st.probe_counts, aux, st.means, st.sigmas, table, levels,
-            st.level_centers, n_clients=cfg.n_clients,
-            batch=cfg.probe_batch)
-    else:
-        pos, feats = features(st, cfg, params, t_s, k_pred)
-        evals = evaluate(st, feats)
-    # churn stage (event-driven fleet): departed clients neither report
-    # evaluations nor get selected.  Statically gated — churn_rate == 0
-    # compiles the exact pre-churn graph, which the event server's
-    # sync-parity pin (tests/test_async.py) rests on.
-    if cfg.churn_rate > 0.0:
-        active = coverage_active(pos, road_length_m=cfg.road_length_m,
-                                 churn_rate=cfg.churn_rate)
-        evals = jnp.where(active, evals, 0.0)
-    scheme = get_scheme(cfg.scheme)
-    windowed = None
-    if cfg.elect == "windowed" and scheme.select_windowed is not None:
-        windowed = scheme.select_windowed(cfg, pos, evals, k_sel)
-    if windowed is not None:
-        mask, elect_overflow = windowed
-    else:
-        mask = select(cfg, pos, evals, k_sel)
-        elect_overflow = jnp.int32(0)
-    if cfg.churn_rate > 0.0:
-        mask = jnp.where(active, mask, 0)
-    survivors, n_straggler = deadline_filter(st, cfg, pos, mask, k_upload)
-    # event-server inputs: absolute completion instants + presence at
-    # upload time (a client leaving coverage mid-training/upload loses
-    # its pending update)
-    t_done = completion_time_s(st, cfg, pos, k_upload, t_s)
-    if cfg.churn_rate > 0.0:
-        pos_done = positions_jax(st.x0, st.speeds, st.jitter_phase, t_done,
-                                 road_length_m=cfg.road_length_m,
-                                 speed_jitter=cfg.speed_jitter)
-        alive_at_done = coverage_active(pos_done,
-                                        road_length_m=cfg.road_length_m,
-                                        churn_rate=cfg.churn_rate)
-        n_active = active.sum()
-    else:
-        alive_at_done = jnp.ones_like(survivors)
-        n_active = jnp.asarray(cfg.n_clients, jnp.int32)
-    stats = selection_stats(mask, evals)
+    with jax.named_scope("probe"):
+        if cfg.fused_probe:
+            # fused fast path: probe forward + Eq. 8 + Mamdani as one op
+            # — a single kernel launch on the Pallas impl, one fused XLA
+            # subgraph on the jnp impl (plus the tight probe pack built
+            # by FLSimulation when the flag is on)
+            ta_raw = predicted_throughput_jax(cfg.network, pos, k_pred)
+            aux = jnp.stack([st.n_valid, ta_raw, 1.0 / st.slowdown],
+                            axis=1).astype(jnp.float32)
+            table, levels = _rules()
+            feats, evals = kops.probe_fuzzy(
+                params, st.probe_images, st.probe_labels, st.probe_seg,
+                st.probe_counts, aux, st.means, st.sigmas, table, levels,
+                st.level_centers, n_clients=cfg.n_clients,
+                batch=cfg.probe_batch)
+        else:
+            feats = _raw_features(st, cfg, params, pos, k_pred)
+            evals = evaluate(st, feats)
+    with jax.named_scope("elect"):
+        # churn stage (event-driven fleet): departed clients neither
+        # report evaluations nor get selected.  Statically gated —
+        # churn_rate == 0 compiles the exact pre-churn graph, which the
+        # event server's sync-parity pin (tests/test_async.py) rests on.
+        if cfg.churn_rate > 0.0:
+            active = coverage_active(pos, road_length_m=cfg.road_length_m,
+                                     churn_rate=cfg.churn_rate)
+            evals = jnp.where(active, evals, 0.0)
+        scheme = get_scheme(cfg.scheme)
+        windowed = None
+        if cfg.elect == "windowed" and scheme.select_windowed is not None:
+            windowed = scheme.select_windowed(cfg, pos, evals, k_sel)
+        if windowed is not None:
+            mask, elect_overflow = windowed
+        else:
+            mask = select(cfg, pos, evals, k_sel)
+            elect_overflow = jnp.int32(0)
+        if cfg.churn_rate > 0.0:
+            mask = jnp.where(active, mask, 0)
+        stats = selection_stats(mask, evals)
+    with jax.named_scope("deadline"):
+        survivors, n_straggler = deadline_filter(st, cfg, pos, mask,
+                                                 k_upload)
+        # event-server inputs: absolute completion instants + presence
+        # at upload time (a client leaving coverage mid-training/upload
+        # loses its pending update)
+        t_done = completion_time_s(st, cfg, pos, k_upload, t_s)
+        if cfg.churn_rate > 0.0:
+            pos_done = positions_jax(st.x0, st.speeds, st.jitter_phase,
+                                     t_done,
+                                     road_length_m=cfg.road_length_m,
+                                     speed_jitter=cfg.speed_jitter)
+            alive_at_done = coverage_active(pos_done,
+                                            road_length_m=cfg.road_length_m,
+                                            churn_rate=cfg.churn_rate)
+            n_active = active.sum()
+        else:
+            alive_at_done = jnp.ones_like(survivors)
+            n_active = jnp.asarray(cfg.n_clients, jnp.int32)
     return {"pos": pos, "feats": feats, "evals": evals, "mask": mask,
             "survivors": survivors, "n_straggler": n_straggler,
             "t_done": t_done, "alive_at_done": alive_at_done,
@@ -362,10 +376,19 @@ def cohort_bucket(k: int) -> int:
     return max(2, k + (k % 2))
 
 
+def _count_cohort(counters: Optional[RoundCounters], k: int,
+                 bucket: int) -> None:
+    """Count ``k`` survivors trained in a bucket of ``bucket`` slots."""
+    if counters is not None:
+        counters.cohort_rows += k
+        counters.cohort_pad_rows += bucket - k
+
+
 def train_groups(params: Params, groups: Sequence[ClientGroup],
                  group_steps: Sequence[int], survivors: np.ndarray,
                  keys: jax.Array, *, epochs: int, batch_size: int,
-                 lr: float, prox_mu: float, return_entries: bool = False
+                 lr: float, prox_mu: float, return_entries: bool = False,
+                 counters: Optional[RoundCounters] = None
                  ) -> Optional[Tuple]:
     """Local-training stage (Eq. 1): one ``vmap(local_train)`` per
     capacity group over that group's surviving cohort.
@@ -386,7 +409,10 @@ def train_groups(params: Params, groups: Sequence[ClientGroup],
     returns ``(merged, weights (np), client_ids (np))`` instead — the
     per-row global client ids let the caller split the stack's FedAvg
     weights across aggregation ticks without re-gathering (padding rows
-    keep weight zero and duplicate the cohort head's id)."""
+    keep weight zero and duplicate the cohort head's id).
+
+    ``counters`` (a ``trace.RoundCounters``) counts the cohort rows and
+    padding slots trained."""
     if not survivors.any():
         return None
     stacks, weights, row_ids = [], [], []
@@ -396,6 +422,7 @@ def train_groups(params: Params, groups: Sequence[ClientGroup],
         if k == 0:
             continue                         # empty cohort: skip group
         bucket = cohort_bucket(k)
+        _count_cohort(counters, k, bucket)
         idx = np.concatenate([cohort, np.full(bucket - k, cohort[0])])
         stacked, _ = local_train_batch_donated(
             params, jnp.asarray(g.images[idx]), jnp.asarray(g.labels[idx]),
@@ -414,11 +441,15 @@ def train_groups(params: Params, groups: Sequence[ClientGroup],
     return merged, jnp.asarray(np.concatenate(weights))
 
 
+def fedavg_round(merged: Params, weights: jax.Array) -> Params:
+    """The round's FedAvg over the merged cohort stacks (Eq. 2)."""
+    return fedavg_masked(merged, weights)
+
+
 # the merged (sum-of-buckets, ...) model stack is the round's largest
-# fresh buffer (bucket x ~1.66M floats) — donate it into the FedAvg
-_fedavg_masked_donated = jax.jit(
-    lambda merged, weights: fedavg_masked(merged, weights),
-    donate_argnums=(0,))
+# fresh buffer (bucket x ~1.66M floats) — donate it into the FedAvg; the
+# executable is named jit_fedavg_round in a device trace
+_fedavg_round_donated = jax.jit(fedavg_round, donate_argnums=(0,))
 
 
 def aggregate(params: Params,
@@ -429,7 +460,7 @@ def aggregate(params: Params,
     if trained is None:
         return params
     merged, weights = trained
-    return _fedavg_masked_donated(merged, weights)
+    return _fedavg_round_donated(merged, weights)
 
 
 # --------------------------------------------------------------------------
@@ -501,108 +532,116 @@ def _sharded_prefix_fn(cfg: StageConfig, mesh: Mesh, seeds: bool):
         gid = i * shard_n + jnp.arange(shard_n)
         valid = gid < n                      # False on dummy pad clients
 
-        # stage: positions + raw features (elementwise in the shard)
-        pos = positions_jax(x0, speeds, jphase, t_s,
-                            road_length_m=cfg.road_length_m,
-                            speed_jitter=cfg.speed_jitter)
-        ta = predicted_throughput_from_fields(cfg.network, pos, pin_shadow,
-                                              loss_u)
-        # Eq. 7 over the local probe shard; every client's samples live
-        # on its owner device, so the psum adds exact zeros elsewhere.
-        # The fused fast path swaps in the fused probe op (one Pallas
-        # launch per shard under the pallas impl; the psum seam below
-        # and the Eq. 8 pmax stay outside the kernel by design).
-        if cfg.fused_probe:
-            lf_part = kops.probe_loss(params, pim, plb, pseg, counts,
-                                      n_clients=n, batch=cfg.probe_batch)
-        else:
-            lf_part = dataset_loss_packed(params, pim, plb, pseg, counts,
+        # stages under the unsharded prefix's named scopes
+        # (trace.SCOPES); positions and raw features are elementwise
+        with jax.named_scope("positions"):
+            pos = positions_jax(x0, speeds, jphase, t_s,
+                                road_length_m=cfg.road_length_m,
+                                speed_jitter=cfg.speed_jitter)
+        with jax.named_scope("probe"):
+            ta = predicted_throughput_from_fields(cfg.network, pos,
+                                                  pin_shadow, loss_u)
+            # Eq. 7 over the local probe shard; every client's samples
+            # live on its owner device, so the psum adds exact zeros
+            # elsewhere.  The fused fast path swaps in the fused probe op
+            # (one Pallas launch per shard under the pallas impl; the
+            # psum seam below and the Eq. 8 pmax stay outside the kernel
+            # by design).
+            if cfg.fused_probe:
+                lf_part = kops.probe_loss(params, pim, plb, pseg, counts,
                                           n_clients=n,
                                           batch=cfg.probe_batch)
-        lf_full = jax.lax.psum(lf_part, CLIENT_AXIS)
-        lf = jax.lax.dynamic_slice_in_dim(jnp.pad(lf_full, (0, pad)),
-                                          i * shard_n, shard_n)
-        feats = jnp.stack([n_valid, ta, 1.0 / slowdown, lf],
-                          axis=1).astype(jnp.float32)
+            else:
+                lf_part = dataset_loss_packed(params, pim, plb, pseg,
+                                              counts, n_clients=n,
+                                              batch=cfg.probe_batch)
+            lf_full = jax.lax.psum(lf_part, CLIENT_AXIS)
+            lf = jax.lax.dynamic_slice_in_dim(jnp.pad(lf_full, (0, pad)),
+                                              i * shard_n, shard_n)
+            feats = jnp.stack([n_valid, ta, 1.0 / slowdown, lf],
+                              axis=1).astype(jnp.float32)
 
-        # stage: fuzzy evaluation with the Eq. 8 maxima pmax'd globally
-        col_max = jax.lax.pmax(
-            jnp.where(valid[:, None], feats, -jnp.inf).max(axis=0),
-            CLIENT_AXIS)
-        evals = kops.fuzzy_eval(feats, means, sigmas, table, levels,
-                                centers, normalize=True, col_maxima=col_max)
-        evals = jnp.where(valid, evals, 0.0)
+            # fuzzy evaluation with the Eq. 8 maxima pmax'd globally
+            col_max = jax.lax.pmax(
+                jnp.where(valid[:, None], feats, -jnp.inf).max(axis=0),
+                CLIENT_AXIS)
+            evals = kops.fuzzy_eval(feats, means, sigmas, table, levels,
+                                    centers, normalize=True,
+                                    col_maxima=col_max)
+            evals = jnp.where(valid, evals, 0.0)
 
-        # churn stage (statically gated, exactly like the unsharded
-        # prefix): departed clients report no evaluation and cannot be
-        # selected; the active mask gathers with the evals so the
-        # selection sees the identical (N,) inputs
-        if cfg.churn_rate > 0.0:
-            active = coverage_active(pos, road_length_m=cfg.road_length_m,
-                                     churn_rate=cfg.churn_rate)
-            evals = jnp.where(active, evals, 0.0)
-
-        # stage: selection.  elect="windowed" keeps the election
-        # shard-local — segment re-bucketing + a ppermute halo ring for
-        # the DCS window, a hierarchical top-k for the CCS quota, and
-        # psum'd stats — so no (N,) vector is ever gathered.  The gather
-        # seam below remains the fallback (and the bit-identity anchor:
-        # a non-zero overflow flag makes the round driver re-run the
-        # round through it).
-        scheme = get_scheme(cfg.scheme)
-        windowed = None
-        if cfg.elect == "windowed" and scheme.select_sharded is not None:
-            ctx = ShardCtx(axis=CLIENT_AXIS, n=n, n_shards=k,
-                           shard_n=shard_n, pad=pad, gid=gid, valid=valid)
-            windowed = scheme.select_sharded(cfg, ctx, pos, evals, k_sel)
-        if windowed is not None:
-            mask, ovf_local = windowed
-            mask = jnp.where(valid, mask, 0)
+        with jax.named_scope("elect"):
+            # churn stage (statically gated, exactly like the unsharded
+            # prefix): departed clients report no evaluation and cannot be
+            # selected; the active mask gathers with the evals so the
+            # selection sees the identical (N,) inputs
             if cfg.churn_rate > 0.0:
-                mask = jnp.where(active, mask, 0)
-            elect_overflow = jax.lax.pmax(ovf_local, CLIENT_AXIS)
-            n_sel = jax.lax.psum(mask.sum(), CLIENT_AXIS)
-            ev_sel = jax.lax.psum((evals * mask).sum(), CLIENT_AXIS)
-            mean_ev_sel = jnp.where(n_sel > 0,
-                                    ev_sel / jnp.maximum(n_sel, 1), 0.0)
-        else:
-            ev_g = jax.lax.all_gather(evals, CLIENT_AXIS, tiled=True)[:n]
-            pos_g = jax.lax.all_gather(pos, CLIENT_AXIS, tiled=True)[:n]
-            mask_g = select(cfg, pos_g, ev_g, k_sel)
-            if cfg.churn_rate > 0.0:
-                act_g = jax.lax.all_gather(active, CLIENT_AXIS,
-                                           tiled=True)[:n]
-                mask_g = jnp.where(act_g, mask_g, 0)
-            mask = jax.lax.dynamic_slice_in_dim(jnp.pad(mask_g, (0, pad)),
-                                                i * shard_n, shard_n)
-            elect_overflow = jnp.int32(0)
-            stats = selection_stats(mask_g, ev_g)
-            n_sel = stats["n_selected"]
-            mean_ev_sel = stats["mean_eval_selected"]
-
-        # stage: Eq. 6 deadline, shard-local again
-        train_t = training_time_s(cfg.timing, slowdown, n_valid)
-        upload_t = upload_time_s_from_shadow(cfg.network, pos,
-                                             cfg.model_bytes, up_shadow)
-        ok = completes_before_deadline(cfg.timing, train_t, upload_t)
-        selected = mask > 0
-        survivors = selected & ok & valid
-        n_straggler = jax.lax.psum((selected & ~ok & valid).sum(),
-                                   CLIENT_AXIS)
-        n_survivor = jax.lax.psum(survivors.sum(), CLIENT_AXIS)
-        # event-server inputs, shard-local like the deadline stage
-        t_done = t_s + train_t + upload_t
-        if cfg.churn_rate > 0.0:
-            pos_done = positions_jax(x0, speeds, jphase, t_done,
-                                     road_length_m=cfg.road_length_m,
-                                     speed_jitter=cfg.speed_jitter)
-            alive_done = coverage_active(pos_done,
-                                         road_length_m=cfg.road_length_m,
+                active = coverage_active(pos, road_length_m=cfg.road_length_m,
                                          churn_rate=cfg.churn_rate)
-            n_active = jax.lax.psum((active & valid).sum(), CLIENT_AXIS)
-        else:
-            alive_done = jnp.ones_like(survivors)
-            n_active = jnp.asarray(n, jnp.int32)
+                evals = jnp.where(active, evals, 0.0)
+
+            # stage: selection.  elect="windowed" keeps the election
+            # shard-local — segment re-bucketing + a ppermute halo ring for
+            # the DCS window, a hierarchical top-k for the CCS quota, and
+            # psum'd stats — so no (N,) vector is ever gathered.  The gather
+            # seam below remains the fallback (and the bit-identity anchor:
+            # a non-zero overflow flag makes the round driver re-run the
+            # round through it).
+            scheme = get_scheme(cfg.scheme)
+            windowed = None
+            if cfg.elect == "windowed" and scheme.select_sharded is not None:
+                ctx = ShardCtx(axis=CLIENT_AXIS, n=n, n_shards=k,
+                               shard_n=shard_n, pad=pad, gid=gid, valid=valid)
+                windowed = scheme.select_sharded(cfg, ctx, pos, evals, k_sel)
+            if windowed is not None:
+                mask, ovf_local = windowed
+                mask = jnp.where(valid, mask, 0)
+                if cfg.churn_rate > 0.0:
+                    mask = jnp.where(active, mask, 0)
+                elect_overflow = jax.lax.pmax(ovf_local, CLIENT_AXIS)
+                n_sel = jax.lax.psum(mask.sum(), CLIENT_AXIS)
+                ev_sel = jax.lax.psum((evals * mask).sum(), CLIENT_AXIS)
+                mean_ev_sel = jnp.where(n_sel > 0,
+                                        ev_sel / jnp.maximum(n_sel, 1), 0.0)
+            else:
+                ev_g = jax.lax.all_gather(evals, CLIENT_AXIS, tiled=True)[:n]
+                pos_g = jax.lax.all_gather(pos, CLIENT_AXIS, tiled=True)[:n]
+                mask_g = select(cfg, pos_g, ev_g, k_sel)
+                if cfg.churn_rate > 0.0:
+                    act_g = jax.lax.all_gather(active, CLIENT_AXIS,
+                                               tiled=True)[:n]
+                    mask_g = jnp.where(act_g, mask_g, 0)
+                mask = jax.lax.dynamic_slice_in_dim(jnp.pad(mask_g, (0, pad)),
+                                                    i * shard_n, shard_n)
+                elect_overflow = jnp.int32(0)
+                stats = selection_stats(mask_g, ev_g)
+                n_sel = stats["n_selected"]
+                mean_ev_sel = stats["mean_eval_selected"]
+
+        with jax.named_scope("deadline"):
+            # Eq. 6, shard-local again
+            train_t = training_time_s(cfg.timing, slowdown, n_valid)
+            upload_t = upload_time_s_from_shadow(cfg.network, pos,
+                                                 cfg.model_bytes, up_shadow)
+            ok = completes_before_deadline(cfg.timing, train_t, upload_t)
+            selected = mask > 0
+            survivors = selected & ok & valid
+            n_straggler = jax.lax.psum((selected & ~ok & valid).sum(),
+                                       CLIENT_AXIS)
+            n_survivor = jax.lax.psum(survivors.sum(), CLIENT_AXIS)
+            # event-server inputs, shard-local like the deadline stage
+            t_done = t_s + train_t + upload_t
+            if cfg.churn_rate > 0.0:
+                pos_done = positions_jax(x0, speeds, jphase, t_done,
+                                         road_length_m=cfg.road_length_m,
+                                         speed_jitter=cfg.speed_jitter)
+                alive_done = coverage_active(pos_done,
+                                             road_length_m=cfg.road_length_m,
+                                             churn_rate=cfg.churn_rate)
+                n_active = jax.lax.psum((active & valid).sum(), CLIENT_AXIS)
+            else:
+                alive_done = jnp.ones_like(survivors)
+                n_active = jnp.asarray(n, jnp.int32)
         return (pos, feats, evals, mask, survivors, n_straggler,
                 t_done, alive_done, n_active,
                 n_sel, n_survivor, mean_ev_sel, elect_overflow)
@@ -787,7 +826,8 @@ def train_groups_sharded(params: Params, groups: Sequence[ClientGroup],
                          group_steps: Sequence[int], survivors: np.ndarray,
                          keys: jax.Array, mesh: Mesh, *, epochs: int,
                          batch_size: int, lr: float, prox_mu: float,
-                         weight_scale: float = 1.0
+                         weight_scale: float = 1.0,
+                         counters: Optional[RoundCounters] = None
                          ) -> Optional[Tuple[Params, jax.Array]]:
     """Mesh-sharded ``train_groups``: per capacity group, each device
     trains its shard of the surviving cohort; the Eq. 2 numerator/
@@ -798,7 +838,8 @@ def train_groups_sharded(params: Params, groups: Sequence[ClientGroup],
     ``weight_scale`` multiplies every cohort weight — the event-driven
     server's per-tick staleness factor (one landing tick shares one
     delay, hence one scalar).  The default 1.0 leaves the weights
-    bitwise untouched (the sync-parity pin)."""
+    bitwise untouched (the sync-parity pin).  ``counters`` as in
+    ``train_groups``."""
     if not survivors.any():
         return None
     shards = mesh_client_shards(mesh)
@@ -809,6 +850,7 @@ def train_groups_sharded(params: Params, groups: Sequence[ClientGroup],
         if k == 0:
             continue                         # empty cohort: skip group
         bucket = cohort_bucket_sharded(k, shards)
+        _count_cohort(counters, k, bucket)
         idx = np.concatenate([cohort, np.full(bucket - k, cohort[0])])
         w = g.n_valid[idx].astype(np.float32)
         if weight_scale != 1.0:

@@ -75,7 +75,7 @@ from repro.core.overhead import (accumulated_time_s, IoVParams,
                                  model_upload_bytes,
                                  state_maintenance_bytes)
 from repro.data.synthetic import make_dataset, train_test_split
-from repro.fl import pipeline
+from repro.fl import pipeline, trace
 from repro.fl.aggregation import fedavg
 from repro.fl.client import (evaluate_accuracy_async, local_train,
                              local_train_batch_donated)
@@ -126,11 +126,16 @@ def checkpoint_round(driver, ckpt, rnd: int, rows, *,
                      lead: bool = True) -> None:
     """Snapshot the end-of-round state when due (lead process only),
     then announce the fault-injection events the chaos suite keys on."""
-    if ckpt is not None and lead and ckpt.due(rnd):
-        ckpt.save_round(rnd, driver.capture_state(),
-                        extra={"rows": rows, "next_round": rnd + 1})
-        faults.fire("checkpoint-saved", round=rnd)
-    faults.fire("round-done", round=rnd)
+    with trace.span(trace.CHECKPOINT, round=rnd):
+        if ckpt is not None and lead and ckpt.due(rnd):
+            ckpt.save_round(rnd, driver.capture_state(),
+                            extra={"rows": rows, "next_round": rnd + 1})
+            faults.fire("checkpoint-saved", round=rnd)
+        faults.fire("round-done", round=rnd)
+
+
+def _elect_overflowed(host: Dict) -> bool:
+    return int(np.max(host.get("elect_overflow", 0))) != 0
 
 
 @dataclass
@@ -261,6 +266,8 @@ class FLSimulation:
         # lifetime per-client participation counts (selection mask hits);
         # checkpointed so budget/fairness schemes survive preemption
         self.participation = np.zeros(self.n, np.int64)
+        # what the rounds did, read by the launchers
+        self.counters = trace.RoundCounters()
         self.statics = self._build_statics()
         self.stage_cfg = self._build_stage_cfg()
 
@@ -471,9 +478,24 @@ class FLSimulation:
         with the gather election and use that state instead.  The prefix
         is pure in ``(params, rnd)``, so the re-run sees identical
         inputs — the returned masks are exactly the dense election's."""
-        if int(np.max(host.get("elect_overflow", 0))) == 0:
+        if not _elect_overflowed(host):
             return host
         return jax.device_get(self.selection_state(rnd, elect="gather"))
+
+    def gather_selection(self, rnd: int,
+                         state: Dict[str, jax.Array]) -> Dict:
+        """The cohort-gather fence of round ``rnd``: the prefix state
+        read to the host, re-run dense when the windowed election
+        overflowed (``resolve_elect_overflow``).  Every driver reads the
+        round's selection here, under the ``fl.fence`` span."""
+        with trace.span(trace.FENCE, round=rnd):
+            host = jax.device_get(state)
+            self.counters.rounds += 1
+            if _elect_overflowed(host):
+                self.counters.elect_reruns += 1
+                with trace.span(trace.ELECT_RERUN, round=rnd):
+                    host = self.resolve_elect_overflow(rnd, host)
+        return host
 
     def _comm_accounting(self, n_selected: int) -> Dict[str, float]:
         """Per-round communication (bytes and time) per §4.2 / Fig. 9,
@@ -524,6 +546,7 @@ class FLSimulation:
                 prox_mu=cfg.prox_mu)
             new_models.append(p_i)
             weights.append(float(self.n_valid[i]))
+        self.counters.cohort_rows += len(new_models)
         if new_models:                           # Eq. 2
             self.params = fedavg(new_models, weights)
 
@@ -592,13 +615,14 @@ class FLSimulation:
             trained = pipeline.train_groups_sharded(
                 self.params, self.groups, self._group_steps, survivors,
                 keys, self.client_mesh, epochs=cfg.local_epochs,
-                batch_size=cfg.batch_size, lr=cfg.lr, prox_mu=cfg.prox_mu)
+                batch_size=cfg.batch_size, lr=cfg.lr, prox_mu=cfg.prox_mu,
+                counters=self.counters)
             self.params = pipeline.aggregate_sharded(self.params, trained)
             return
         trained = pipeline.train_groups(
             self.params, self.groups, self._group_steps, survivors, keys,
             epochs=cfg.local_epochs, batch_size=cfg.batch_size, lr=cfg.lr,
-            prox_mu=cfg.prox_mu)
+            prox_mu=cfg.prox_mu, counters=self.counters)
         self.params = pipeline.aggregate(self.params, trained)
 
     # ------------------------------------------------------------------
@@ -613,11 +637,12 @@ class FLSimulation:
         may come from a seed-vmapped sweep dispatch).  This is the single
         device->host crossing of the round — the survivor mask becomes
         concrete here, at the cohort gather."""
-        host = self.resolve_elect_overflow(rnd, jax.device_get(state))
+        host = self.gather_selection(rnd, state)
         self._dispatch_training(rnd, host)
-        acc, n_test = evaluate_accuracy_async(
-            self._eval_params(), self.test_images, self.test_labels,
-            batch=256)
+        with trace.span(trace.DISPATCH, round=rnd):
+            acc, n_test = evaluate_accuracy_async(
+                self._eval_params(), self.test_images, self.test_labels,
+                batch=256)
         return self._round_row(rnd, host, acc, n_test)
 
     def _eval_params(self):
@@ -632,13 +657,14 @@ class FLSimulation:
         """Steps 5 + 7 from a host-side prefix state: cohort gather and
         training/aggregation dispatch.  Returns as soon as the work is
         enqueued — ``self.params`` becomes a device future."""
-        survivors = np.asarray(host["survivors"])
-        self._record_participation(host["mask"])
-        keys = self._round_keys(rnd)
-        if self.run_cfg.engine == "batched":
-            self._train_batched(survivors, keys)
-        else:
-            self._train_loop(survivors, keys)
+        with trace.span(trace.COHORT, round=rnd):
+            survivors = np.asarray(host["survivors"])
+            self._record_participation(host["mask"])
+            keys = self._round_keys(rnd)
+            if self.run_cfg.engine == "batched":
+                self._train_batched(survivors, keys)
+            else:
+                self._train_loop(survivors, keys)
 
     def _record_participation(self, mask) -> None:
         """Track the round's selection mask and bump the lifetime
@@ -723,20 +749,21 @@ class FLSimulation:
         synchronous barrier they are the degenerate values (everything
         active and on time) and the event server overrides them from its
         tick counters."""
-        n_selected = int(host["n_selected"])
-        survivors = np.asarray(host["survivors"])
-        n_agg = int(survivors.sum())
-        row = {"round": rnd,
-               "accuracy": float(acc_count) / float(n_test),
-               "n_selected": n_selected,
-               "n_aggregated": n_agg,
-               "n_straggler": int(host["n_straggler"]),
-               "n_active": int(host.get("n_active", self.n)),
-               "stale_frac": 0.0,
-               "n_effective": float(n_agg),
-               "rounds_behind_hist": f"{n_agg}/0/0/0",
-               "mean_eval_selected": float(host["mean_eval_selected"])}
-        row.update(self._comm_accounting(n_selected))
+        with trace.span(trace.READ, round=rnd):
+            n_selected = int(host["n_selected"])
+            survivors = np.asarray(host["survivors"])
+            n_agg = int(survivors.sum())
+            row = {"round": rnd,
+                   "accuracy": float(acc_count) / float(n_test),
+                   "n_selected": n_selected,
+                   "n_aggregated": n_agg,
+                   "n_straggler": int(host["n_straggler"]),
+                   "n_active": int(host.get("n_active", self.n)),
+                   "stale_frac": 0.0,
+                   "n_effective": float(n_agg),
+                   "rounds_behind_hist": f"{n_agg}/0/0/0",
+                   "mean_eval_selected": float(host["mean_eval_selected"])}
+            row.update(self._comm_accounting(n_selected))
         return row
 
     def run(self, n_rounds: Optional[int] = None,
@@ -769,8 +796,9 @@ class FLSimulation:
                                        checkpointer=ckpt)
         lead = not self.multihost or jax.process_index() == 0
         for r in range(start, n):
-            rows.append(self.run_round(r))
-            checkpoint_round(self, ckpt, r, rows, lead=lead)
+            with trace.round_span(r):
+                rows.append(self.run_round(r))
+                checkpoint_round(self, ckpt, r, rows, lead=lead)
         return rows
 
     def run_overlapped(self, n_rounds: int, *, start: int = 0,
@@ -802,14 +830,17 @@ class FLSimulation:
         lead = not self.multihost or jax.process_index() == 0
         state = self.selection_state(start)
         for r in range(start, n_rounds):
-            host = jax.device_get(state)     # fence: the cohort gather
-            host = self.resolve_elect_overflow(r, host)
-            self._dispatch_training(r, host)
-            acc, n_test = evaluate_accuracy_async(
-                self._eval_params(), self.test_images, self.test_labels,
-                batch=256)
-            if r + 1 < n_rounds:             # round-ahead: r+1's prefix
-                state = self.selection_state(r + 1)
-            rows.append(self._round_row(r, host, acc, n_test))
-            checkpoint_round(self, checkpointer, r, rows, lead=lead)
+            with trace.round_span(r):
+                host = self.gather_selection(r, state)
+                self._dispatch_training(r, host)
+                ahead = r + 1 < n_rounds     # round-ahead: r+1's prefix
+                with trace.span(trace.DISPATCH, round=r,
+                                prefix_round=r + 1 if ahead else None):
+                    acc, n_test = evaluate_accuracy_async(
+                        self._eval_params(), self.test_images,
+                        self.test_labels, batch=256)
+                    if ahead:
+                        state = self.selection_state(r + 1)
+                rows.append(self._round_row(r, host, acc, n_test))
+                checkpoint_round(self, checkpointer, r, rows, lead=lead)
         return rows

@@ -59,31 +59,3 @@ def staleness_weight(lam: float, delay_rounds) -> np.ndarray:
         raise ValueError(f"delay_rounds must be >= 0: {delay_rounds}")
     return 1.0 / (1.0 + lam * delay_rounds)
 
-
-def measure_b_exe(batch_size: int = 20, repeats: int = 3) -> float:
-    """Measure B_exe for the paper's CNN on *this* host (DESIGN.md §4)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs.mnist_cnn import CONFIG as CNN_CFG
-    from repro.models.cnn import cnn_loss, init_cnn
-    from repro.train.optim import sgd_update
-
-    params = init_cnn(jax.random.PRNGKey(0), CNN_CFG)
-    imgs = jnp.zeros((batch_size, 28, 28, 1))
-    lbls = jnp.zeros((batch_size,), jnp.int32)
-
-    @jax.jit
-    def step(p):
-        (l, _), g = jax.value_and_grad(cnn_loss, has_aux=True)(p, imgs, lbls)
-        return sgd_update(p, g, 0.01)
-
-    params = step(params)                      # compile
-    jax.block_until_ready(params)
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        params = step(params)
-    jax.block_until_ready(params)
-    return (time.perf_counter() - t0) / repeats

@@ -149,7 +149,7 @@ def main(argv=None) -> int:
             if is_lead:
                 print(f"[fl_sim] {scheme}: final acc {accs[-1]:.3f} "
                       f"(best {max(accs):.3f}), avg selected {nsel:.2f}, "
-                      f"{dt:.0f}s", flush=True)
+                      f"{dt:.0f}s; {sim.counters}", flush=True)
             results[scheme] = hist
     if args.out and is_lead:     # one writer in a multi-process launch
         from repro.ioutil import write_atomic_json

@@ -70,13 +70,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl import pipeline
+from repro.fl import pipeline, trace
 from repro.fl.async_server import EventDrivenServer
 from repro.fl.client import evaluate_accuracy_async
 from repro.fl.mobility import MobilityConfig
 from repro.fl.partition import PartitionConfig
 from repro.fl.rounds import FLSimConfig, FLSimulation
 from repro.fl.runconfig import RunConfig, add_run_arguments
+from repro.fl.trace import RoundCounters
 from repro.ioutil import write_atomic
 from repro.launch import faults
 from repro.sharding.api import sweep_devices
@@ -153,7 +154,8 @@ def run_seed_group(scheme: str, classes_per_client: int, distribution: str,
                    run: Optional[RunConfig] = None,
                    checkpoint_dir: Optional[str] = None,
                    checkpoint_every: int = 1,
-                   resume: bool = False) -> List[Dict]:
+                   resume: bool = False,
+                   counters: Optional[RoundCounters] = None) -> List[Dict]:
     """Run every seed of one cell group for ``rounds`` rounds.
 
     ``run`` is the shared execution profile (``RunConfig``): the sync
@@ -183,7 +185,10 @@ def run_seed_group(scheme: str, classes_per_client: int, distribution: str,
     seed's driver state in one ``RoundCheckpointer`` entry, plus the
     rows emitted so far); ``resume=True`` restores the latest good
     snapshot so a killed group replays only its unfinished rounds —
-    bit-identically."""
+    bit-identically.
+
+    ``counters`` (a ``RoundCounters``), when given, gains every seed's
+    round counters."""
     run = (run if run is not None else RunConfig()).resolved()
     if overlap is None:
         overlap = run.overlap_rounds
@@ -248,36 +253,47 @@ def run_seed_group(scheme: str, classes_per_client: int, distribution: str,
     lead = jax.process_index() == 0
     states = None
     for r in range(start, rounds):
-        if states is None:
-            states = dispatch(r)
-        nxt = None
-        if overlap:
-            # the device_get fence also surfaces elect_overflow: any
-            # flagged seed re-runs its prefix through the dense gather
-            # before training, keeping windowed masks bit-identical
-            hosts = [sim.resolve_elect_overflow(r, jax.device_get(s))
-                     for sim, s in zip(sims, states)]
-            for drv, host in zip(drivers, hosts):    # train dispatch
-                drv._dispatch_training(r, host)
-            pend = [evaluate_accuracy_async(sim._eval_params(),
-                                            sim.test_images,
-                                            sim.test_labels, batch=256)
-                    for sim in sims]
-            if r + 1 < rounds:                       # round-ahead
-                nxt = dispatch(r + 1)
-            for seed, drv, host, (acc, nt) in zip(seeds, drivers, hosts,
-                                                  pend):
-                rows.append(meta(seed, drv._round_row(r, host, acc, nt)))
-        else:
-            for seed, drv, state in zip(seeds, drivers, states):
-                rows.append(meta(seed, drv.finish_round(r, state)))
-        states = nxt
-        if ckpt is not None and lead and ckpt.due(r):
-            ckpt.save_round(
-                r, {"seeds": [drv.capture_state() for drv in drivers]},
-                extra={"rows": rows, "next_round": r + 1})
-            faults.fire("checkpoint-saved", round=r)
-        faults.fire("round-done", round=r)
+        with trace.round_span(r):
+            if states is None:
+                states = dispatch(r)
+            nxt = None
+            if overlap:
+                # the fence also surfaces elect_overflow: any flagged seed
+                # re-runs its prefix through the dense gather before
+                # training, keeping windowed masks bit-identical
+                hosts = [sim.gather_selection(r, s)
+                         for sim, s in zip(sims, states)]
+                for drv, host in zip(drivers, hosts):    # train dispatch
+                    drv._dispatch_training(r, host)
+                ahead = r + 1 < rounds
+                with trace.span(trace.DISPATCH, round=r,
+                                prefix_round=r + 1 if ahead else None):
+                    pend = [evaluate_accuracy_async(sim._eval_params(),
+                                                    sim.test_images,
+                                                    sim.test_labels,
+                                                    batch=256)
+                            for sim in sims]
+                    if ahead:                            # round-ahead
+                        nxt = dispatch(r + 1)
+                for seed, drv, host, (acc, nt) in zip(seeds, drivers, hosts,
+                                                      pend):
+                    rows.append(meta(seed, drv._round_row(r, host, acc,
+                                                          nt)))
+            else:
+                for seed, drv, state in zip(seeds, drivers, states):
+                    rows.append(meta(seed, drv.finish_round(r, state)))
+            states = nxt
+            if ckpt is not None and lead and ckpt.due(r):
+                with trace.span(trace.CHECKPOINT, round=r):
+                    ckpt.save_round(
+                        r, {"seeds": [drv.capture_state()
+                                      for drv in drivers]},
+                        extra={"rows": rows, "next_round": r + 1})
+                faults.fire("checkpoint-saved", round=r)
+            faults.fire("round-done", round=r)
+    if counters is not None:
+        for sim in sims:
+            counters.add(sim.counters)
     return rows
 
 
@@ -430,11 +446,12 @@ def completed_job_rows(parsed: Optional[List[Dict]],
     return out
 
 
-def _run_group_worker(args: Tuple) -> List[Dict]:
-    """Top-level (picklable) worker: one cell group, serial in-process.
-    ``mesh_spec`` (a ``--mesh`` string; Mesh objects don't pickle)
-    rebuilds the client mesh inside the worker's own jax runtime; the
-    frozen ``RunConfig`` pickles by value."""
+def _run_group_worker(args: Tuple) -> Tuple[List[Dict], RoundCounters]:
+    """Top-level (picklable) worker: one cell group, serial in-process;
+    returns its rows and round counters.  ``mesh_spec`` (a ``--mesh``
+    string; Mesh objects don't pickle) rebuilds the client mesh inside
+    the worker's own jax runtime; the frozen ``RunConfig`` pickles by
+    value."""
     scheme, classes, dist, seeds, rounds, cfg_fn, vmap_prefix, \
         mesh_spec, overlap, run, cache_dir, ckpt_dir, ckpt_every, \
         resume = args
@@ -444,11 +461,14 @@ def _run_group_worker(args: Tuple) -> List[Dict]:
         # sibling workers retrace identical executables; the shared
         # persistent cache lets one worker's compile serve the rest
         enable_jit_cache(cache_dir)
-        return run_seed_group(scheme, classes, dist, seeds, rounds,
+        counters = RoundCounters()
+        rows = run_seed_group(scheme, classes, dist, seeds, rounds,
                               cfg_fn=cfg_fn, vmap_prefix=vmap_prefix,
                               overlap=overlap, run=run,
                               checkpoint_dir=ckpt_dir,
-                              checkpoint_every=ckpt_every, resume=resume)
+                              checkpoint_every=ckpt_every, resume=resume,
+                              counters=counters)
+        return rows, counters
 
 
 def sweep(schemes: Sequence[str], classes_list: Sequence[int],
@@ -462,7 +482,8 @@ def sweep(schemes: Sequence[str], classes_list: Sequence[int],
           out_path: Optional[str] = None,
           checkpoint_dir: Optional[str] = None,
           checkpoint_every: int = 1,
-          resume: bool = False) -> List[Dict]:
+          resume: bool = False,
+          counters: Optional[RoundCounters] = None) -> List[Dict]:
     """Run the full grid — every cell under every async scenario — and
     return aggregated tidy rows.
 
@@ -489,7 +510,11 @@ def sweep(schemes: Sequence[str], classes_list: Sequence[int],
     ``_FMT`` formats are parse/format idempotent, so they re-emit
     byte-identically), in-flight groups restart from their round
     checkpoints, and the final CSV is byte-identical to an
-    uninterrupted run's."""
+    uninterrupted run's.
+
+    ``counters`` (a ``RoundCounters``), when given, gains the round
+    counters of every group run."""
+    counters = counters if counters is not None else RoundCounters()
     log = log or (lambda s: None)
     runs = tuple(runs) if runs else (RunConfig().resolved(),)
     jobs: List[Tuple[Group, RunConfig]] = [
@@ -558,8 +583,9 @@ def sweep(schemes: Sequence[str], classes_list: Sequence[int],
         with ProcessPoolExecutor(
                 max_workers=workers,
                 mp_context=mp.get_context("spawn")) as pool:
-            for (i, (s, c, d), run), got in zip(
+            for (i, (s, c, d), run), (got, got_counters) in zip(
                     todo, pool.map(_run_group_worker, work)):
+                counters.add(got_counters)
                 log(f"[sweep] {s} classes={c} {d} "
                     f"churn={run.churn_rate} lam={run.staleness_lambda}: "
                     f"{len(got)} rows")
@@ -578,7 +604,7 @@ def sweep(schemes: Sequence[str], classes_list: Sequence[int],
                                  checkpoint_dir=group_dir(scheme, classes,
                                                           dist, run),
                                  checkpoint_every=checkpoint_every,
-                                 resume=resume)
+                                 resume=resume, counters=counters)
         rows.extend(got)
         finish_group(i, (scheme, classes, dist), run, rows)
         accs = [r["accuracy"] for r in got if r["round"] == rounds - 1]
@@ -702,6 +728,7 @@ def main(argv=None) -> int:
                              or (base_run.agg_cadence_s or 0.0,))
 
     t0 = time.time()
+    counters = RoundCounters()
     cache_dir = resolve_cache_dir(args.jit_cache_dir)
     from repro.launch.cache import enable_jit_cache
     from repro.launch.mesh import client_mesh_context
@@ -727,7 +754,7 @@ def main(argv=None) -> int:
                      out_path=args.out,
                      checkpoint_dir=full_run.checkpoint_dir,
                      checkpoint_every=full_run.checkpoint_every,
-                     resume=full_run.resume)
+                     resume=full_run.resume, counters=counters)
     csv_text = rows_to_csv(rows)
     if is_lead:                  # one writer in a multi-process launch
         write_atomic(args.out, csv_text)
@@ -735,7 +762,7 @@ def main(argv=None) -> int:
               f"({len(schemes)}x{len(classes_list)}x{len(distributions)} "
               f"cells x {len(runs)} scenarios x {args.seeds} seeds x "
               f"{args.rounds} rounds) to {args.out} in "
-              f"{time.time() - t0:.0f}s")
+              f"{time.time() - t0:.0f}s; {counters}")
     return 0
 
 
