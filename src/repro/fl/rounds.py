@@ -527,12 +527,12 @@ class FLSimulation:
                 "comm_time_s": comm_t}
 
     # -- local training + aggregation (steps 5-7) ----------------------
-    def _train_loop(self, survivors: np.ndarray,
-                    keys: jax.Array) -> None:
+    def _train_loop(self, survivors: np.ndarray, keys: jax.Array):
         """Reference path: per-client jitted local_train calls + list
-        FedAvg over the survivors.  An empty round is a no-op broadcast.
-        Each client trains at its own capacity group's cap/steps, so the
-        per-client math matches the grouped batched engine exactly."""
+        FedAvg over the survivors -> the new global params.  An empty
+        round is a no-op broadcast (``self.params`` itself).  Each client
+        trains at its own capacity group's cap/steps, so the per-client
+        math matches the grouped batched engine exactly."""
         cfg = self.cfg
         new_models, weights = [], []
         for i in np.where(survivors)[0]:
@@ -547,8 +547,9 @@ class FLSimulation:
             new_models.append(p_i)
             weights.append(float(self.n_valid[i]))
         self.counters.cohort_rows += len(new_models)
-        if new_models:                           # Eq. 2
-            self.params = fedavg(new_models, weights)
+        if not new_models:
+            return self.params
+        return fedavg(new_models, weights)       # Eq. 2
 
     # cohort bucketing lives with the staged training stage now
     _bucket = staticmethod(pipeline.cohort_bucket)
@@ -600,16 +601,16 @@ class FLSimulation:
                     steps_per_epoch=self._group_steps[gi], lr=cfg.lr,
                     prox_mu=cfg.prox_mu)
 
-    def _train_batched(self, survivors: np.ndarray,
-                       keys: jax.Array) -> None:
-        """The staged ``train_groups`` + ``aggregate`` stages: one
-        vmap(local_train) per capacity group over that group's surviving
-        cohort, the mask folded into the FedAvg weights (Eq. 2).
-        Stragglers are dropped at the gather (their update is discarded
-        either way; at IoV scale their local SGD FLOPs are not).  An
-        empty round (or per-group cohort) is a no-op broadcast.  Under a
-        client mesh each device trains its shard of every group's cohort
-        and FedAvg finishes with a cross-device psum."""
+    def _train_batched(self, survivors: np.ndarray, keys: jax.Array):
+        """The staged ``train_groups`` + ``aggregate`` stages -> the new
+        global params: one vmap(local_train) per capacity group over that
+        group's surviving cohort, the mask folded into the FedAvg weights
+        (Eq. 2).  Stragglers are dropped at the gather (their update is
+        discarded either way; at IoV scale their local SGD FLOPs are
+        not).  An empty round (or per-group cohort) is a no-op broadcast
+        (``self.params`` itself).  Under a client mesh each device trains
+        its shard of every group's cohort and FedAvg finishes with a
+        cross-device psum."""
         cfg = self.cfg
         if self.client_mesh is not None:
             trained = pipeline.train_groups_sharded(
@@ -617,13 +618,12 @@ class FLSimulation:
                 keys, self.client_mesh, epochs=cfg.local_epochs,
                 batch_size=cfg.batch_size, lr=cfg.lr, prox_mu=cfg.prox_mu,
                 counters=self.counters)
-            self.params = pipeline.aggregate_sharded(self.params, trained)
-            return
+            return pipeline.aggregate_sharded(self.params, trained)
         trained = pipeline.train_groups(
             self.params, self.groups, self._group_steps, survivors, keys,
             epochs=cfg.local_epochs, batch_size=cfg.batch_size, lr=cfg.lr,
             prox_mu=cfg.prox_mu, counters=self.counters)
-        self.params = pipeline.aggregate(self.params, trained)
+        return pipeline.aggregate(self.params, trained)
 
     # ------------------------------------------------------------------
     def run_round(self, rnd: int) -> Dict[str, float]:
@@ -654,17 +654,29 @@ class FLSimulation:
             else self.params
 
     def _dispatch_training(self, rnd: int, host: Dict) -> None:
-        """Steps 5 + 7 from a host-side prefix state: cohort gather and
-        training/aggregation dispatch.  Returns as soon as the work is
+        """Steps 5 + 7 from a host-side prefix state: the cohort
+        dispatch, then its commit.  Returns as soon as the work is
         enqueued — ``self.params`` becomes a device future."""
+        self._commit_round(host, self._dispatch_cohort(rnd, host))
+
+    def _dispatch_cohort(self, rnd: int, host: Dict):
+        """Round keys, cohort gather and the training/aggregation
+        dispatch of round ``rnd`` -> its new global params (a device
+        future; ``self.params`` itself when nobody trained).  Commits
+        nothing: ``self.params`` and the mask stay the previous round's
+        until ``_commit_round``."""
         with trace.span(trace.COHORT, round=rnd):
             survivors = np.asarray(host["survivors"])
-            self._record_participation(host["mask"])
             keys = self._round_keys(rnd)
             if self.run_cfg.engine == "batched":
-                self._train_batched(survivors, keys)
-            else:
-                self._train_loop(survivors, keys)
+                return self._train_batched(survivors, keys)
+            return self._train_loop(survivors, keys)
+
+    def _commit_round(self, host: Dict, params) -> None:
+        """Make a dispatched round's params the global model and record
+        its selection mask."""
+        self.params = params
+        self._record_participation(host["mask"])
 
     def _record_participation(self, mask) -> None:
         """Track the round's selection mask and bump the lifetime
@@ -813,12 +825,22 @@ class FLSimulation:
         device future as soon as round r's trainers are queued — before
         round r's metrics are read.  The only hard fence per round is
         the ``device_get`` at the cohort gather (survivor indices must
-        be concrete to slice the fixed-shape stacks); the accuracy read
-        happens after the round-ahead dispatch, so the device never
-        idles waiting for host bookkeeping between rounds.  Rounds are
-        bit-identical to the serial driver — same ops in the same
-        order, only enqueued earlier (pinned in
-        tests/test_probe_fuzzy.py).
+        be concrete to slice the fixed-shape stacks).  Round r's
+        accuracy eval reads the same params as prefix r+1 and is queued
+        *behind* it, so the device runs the eval while the host reads
+        round r+1's fence and dispatches its cohort.  Step r therefore:
+
+          1. fence r (the device runs eval r-1);
+          2. dispatches cohort r without committing it;
+          3. reads and checkpoints round r-1 — the checkpointer still
+             sees round r-1's params and mask as the global state;
+          4. commits round r (params, mask, participation);
+          5. dispatches prefix r+1, then eval r.
+
+        The last step reads and checkpoints its own round.  Rounds are
+        bit-identical to the serial driver — the same executables on
+        the same inputs, only enqueued in another order (pinned in
+        tests/test_probe_fuzzy.py and tests/test_trace.py).
 
         Resume slots in transparently: the prefix is pure in
         ``(params, rnd)``, so the round-ahead dispatch a kill threw away
@@ -828,19 +850,30 @@ class FLSimulation:
         if start >= n_rounds:
             return rows
         lead = not self.multihost or jax.process_index() == 0
+
+        def finish(rnd, host, acc, n_test):
+            rows.append(self._round_row(rnd, host, acc, n_test))
+            checkpoint_round(self, checkpointer, rnd, rows, lead=lead)
+
         state = self.selection_state(start)
+        pending = None                   # the round whose eval is queued
         for r in range(start, n_rounds):
             with trace.round_span(r):
                 host = self.gather_selection(r, state)
-                self._dispatch_training(r, host)
+                params = self._dispatch_cohort(r, host)
+                if pending is not None:
+                    finish(*pending)
+                self._commit_round(host, params)
                 ahead = r + 1 < n_rounds     # round-ahead: r+1's prefix
-                with trace.span(trace.DISPATCH, round=r,
+                with trace.span(trace.DISPATCH, round=r, eval_round=r,
                                 prefix_round=r + 1 if ahead else None):
+                    if ahead:
+                        state = self.selection_state(r + 1)
+                        self.counters.evals_behind_prefix += 1
                     acc, n_test = evaluate_accuracy_async(
                         self._eval_params(), self.test_images,
                         self.test_labels, batch=256)
-                    if ahead:
-                        state = self.selection_state(r + 1)
-                rows.append(self._round_row(r, host, acc, n_test))
-                checkpoint_round(self, checkpointer, r, rows, lead=lead)
+                pending = (r, host, acc, n_test)
+                if not ahead:
+                    finish(*pending)
         return rows

@@ -9,13 +9,15 @@ carries the round it serves as its ``round`` argument.
 ======================  ==================================================
 span                    covers
 ======================  ==================================================
-``fl.round`` (step r)   one round of a driver
+``fl.round`` (step r)   one round of a driver (round-ahead, step r
+                        reads and checkpoints round r-1)
 ``fl.fence``            the cohort-gather device read of the prefix state
 ``fl.elect_rerun``      the dense re-run of an overflowed windowed election
 ``fl.cohort``           round keys, cohort slicing, upload, trainer and
                         FedAvg dispatch
-``fl.dispatch``         the accuracy dispatch (and, round-ahead, the next
-                        round's prefix: ``prefix_round``)
+``fl.dispatch``         the accuracy dispatch (round-ahead: the next
+                        round's prefix, ``prefix_round``, then the eval,
+                        ``eval_round``)
 ``fl.read``             the accuracy read and the round's row
 ``fl.checkpoint``       the checkpointer hook
 ======================  ==================================================
@@ -66,12 +68,15 @@ class RoundCounters:
       re-ran the prefix with the dense election;
     - ``cohort_rows``: survivors trained (real cohort rows);
     - ``cohort_pad_rows``: padding slots trained at weight zero to fill
-      a cohort bucket.
+      a cohort bucket;
+    - ``evals_behind_prefix``: rounds whose accuracy eval the
+      round-ahead driver queued behind the next round's prefix.
     """
     rounds: int = 0
     elect_reruns: int = 0
     cohort_rows: int = 0
     cohort_pad_rows: int = 0
+    evals_behind_prefix: int = 0
 
     def add(self, other: "RoundCounters") -> None:
         for f in dataclasses.fields(self):
@@ -81,4 +86,5 @@ class RoundCounters:
     def __str__(self) -> str:
         return (f"{self.rounds} rounds, {self.elect_reruns} election "
                 f"re-runs, {self.cohort_rows} cohort rows trained + "
-                f"{self.cohort_pad_rows} padding")
+                f"{self.cohort_pad_rows} padding, "
+                f"{self.evals_behind_prefix} evals behind the next prefix")

@@ -131,8 +131,8 @@ def test_partial_group_cohort_parity():
     sim_l = _sim("dcs", "loop")
     survivors = np.zeros(N_CLIENTS, bool)
     survivors[[4, 7]] = True                 # small-capacity clients only
-    sim_b._train_batched(survivors, sim_b._round_keys(0))
-    sim_l._train_loop(survivors, sim_l._round_keys(0))
+    sim_b.params = sim_b._train_batched(survivors, sim_b._round_keys(0))
+    sim_l.params = sim_l._train_loop(survivors, sim_l._round_keys(0))
     for a, b in zip(jax.tree.leaves(sim_b.params),
                     jax.tree.leaves(sim_l.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -86,6 +86,55 @@ def test_overlap_resume_parity(tmp_path):
     _kill_resume_parity(tmp_path, overlap=True)
 
 
+class _Stop(Exception):
+    pass
+
+
+class _SaveThenStop(RoundCheckpointer):
+    """Saves round ``stop`` and then raises, as the chip benchmark's
+    window close does: round-ahead, round ``stop + 1``'s cohort is
+    dispatched by then and not yet committed."""
+
+    def __init__(self, directory, stop):
+        super().__init__(directory)
+        self.stop = stop
+
+    def save_round(self, rnd, state, extra=None):
+        super().save_round(rnd, state, extra)
+        if rnd == self.stop:
+            raise _Stop(rnd)
+
+
+def test_overlap_resume_after_uncommitted_cohort(tmp_path):
+    """Round-ahead, stopped from round 1's checkpoint after round 2's
+    cohort was dispatched: the simulation still holds round 1's params,
+    mask and participation, and the resumed rows and params are
+    bit-identical to an uninterrupted run."""
+    ref = FLSimulation(_cfg())
+    ref.run(2, overlap=False)
+    full = FLSimulation(_cfg())
+    rows_full = full.run(4, overlap=True)
+    assert rows_full[2]["n_aggregated"] > 0      # round 2 trains
+
+    ck = _SaveThenStop(str(tmp_path / "ck"), stop=1)
+    part = FLSimulation(_cfg())
+    with pytest.raises(_Stop):
+        part.run(4, overlap=True, checkpointer=ck)
+    assert part.counters.rounds == 3             # round 2's fence read
+    _assert_params_equal(_leaves(part), _leaves(ref))
+    np.testing.assert_array_equal(part.last_mask, ref.last_mask)
+    np.testing.assert_array_equal(part.participation, ref.participation)
+
+    res = FLSimulation(_cfg())
+    rows_res = res.run(4, overlap=True,
+                       checkpointer=RoundCheckpointer(str(tmp_path / "ck")),
+                       resume=True)
+    assert rows_res == rows_full
+    _assert_params_equal(_leaves(full), _leaves(res))
+    np.testing.assert_array_equal(full.last_mask, res.last_mask)
+    np.testing.assert_array_equal(full.participation, res.participation)
+
+
 def test_event_resume_parity(tmp_path):
     """Event-driven server under churn + weighted staleness + a cadence
     faster than the round period: the pending-tick pool crosses the

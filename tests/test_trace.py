@@ -80,25 +80,82 @@ def _profile(fn, tmp_path):
     return _host_spans(str(tmp_path))
 
 
+def _spans_in_steps(spans):
+    """Per ``fl.round`` step: ``(name, round)`` of every span inside it."""
+    steps = sorted((a["step_num"], s, e) for s, e, n, a in spans
+                   if n == trace.ROUND)
+    return {r: sorted((n, a.get("round")) for s, e, n, a in spans
+                      if n != trace.ROUND and lo <= s and e <= hi)
+            for r, lo, hi in steps}
+
+
 def test_round_ahead_spans(tmp_path):
-    """Three round-ahead rounds: three ``fl.round`` steps, each holding
-    its round's fence, cohort, dispatch (with the next round's prefix)
-    and read; the checkpointer hook's span too."""
+    """Three round-ahead rounds: three ``fl.round`` steps.  Step r holds
+    round r's fence, cohort and dispatch (the next round's prefix, then
+    round r's eval), and the read and checkpoint of round r-1; the last
+    step also reads and checkpoints its own round."""
     sim = FLSimulation(_cfg(), run=RunConfig(overlap_rounds=True))
     spans = _profile(lambda: sim.run(ROUNDS, resume=False), tmp_path)
-    by_round = _spans_by_round(spans)
-    assert sorted(by_round) == list(range(ROUNDS))
-    for r, names in by_round.items():
-        assert names == sorted(CHILD_SPANS + (trace.CHECKPOINT,)), names
-    ahead = {a["round"]: a.get("prefix_round") for s, e, n, a in spans
-             if n == trace.DISPATCH}
-    assert ahead == {0: 1, 1: 2, 2: None}
+    got = _spans_in_steps(spans)
+    assert sorted(got) == list(range(ROUNDS))
+    for r, names in got.items():
+        want = [(trace.FENCE, r), (trace.COHORT, r), (trace.DISPATCH, r)]
+        done = [r - 1] if r else []
+        if r == ROUNDS - 1:
+            done.append(r)
+        want += [(n, d) for d in done for n in (trace.READ,
+                                                trace.CHECKPOINT)]
+        assert names == sorted(want), names
+    ahead = {a["round"]: (a.get("prefix_round"), a.get("eval_round"))
+             for s, e, n, a in spans if n == trace.DISPATCH}
+    assert ahead == {0: (1, 0), 1: (2, 1), 2: (None, 2)}
     assert not [n for s, e, n, a in spans if n == trace.ELECT_RERUN]
+
+
+class _Recorder:
+    """A checkpointer that reads the simulation as the benchmark's round
+    clock does: in ``due(r)`` the params and mask are round r's output,
+    and the round trained when the params object changed."""
+
+    def __init__(self, sim):
+        self.sim, self.prev, self.seen = sim, sim.params, []
+
+    def due(self, rnd):
+        p = self.sim.params
+        self.seen.append((rnd, jax.device_get(p),
+                          np.array(self.sim.last_mask), p is not self.prev))
+        self.prev = p
+        return False
+
+
+def test_round_ahead_checkpointer_sees_each_rounds_output():
+    """In every ``due(r)`` of a round-ahead run the checkpointer reads
+    the serial driver's round-r params and mask, by value, and the
+    params-identity test flags the same rounds as trained.  A 10 s
+    deadline leaves rounds 0 and 3 without a survivor."""
+    seen = {}
+    for overlap in (False, True):
+        sim = FLSimulation(_cfg(deadline_s=10.0))
+        rec = _Recorder(sim)
+        sim.run(5, overlap=overlap, checkpointer=rec, resume=False)
+        seen[overlap] = rec.seen
+    assert [s[3] for s in seen[False]] == [False, True, True, False, True]
+    for (rs, ps, ms, ts), (ro, po, mo, to) in zip(seen[False], seen[True],
+                                                  strict=True):
+        assert (rs, ts) == (ro, to)
+        np.testing.assert_array_equal(ms, mo)
+        for a, b in zip(jax.tree.leaves(ps), jax.tree.leaves(po)):
+            np.testing.assert_array_equal(a, b)
+
+
+def _ahead():
+    sim = FLSimulation(_cfg(), run=RunConfig(overlap_rounds=True))
+    return (lambda: sim.run(ROUNDS, resume=False)), sim.counters
 
 
 def _serial():
     sim = FLSimulation(_cfg(), run=RunConfig(overlap_rounds=False))
-    return lambda: sim.run(ROUNDS, resume=False)
+    return (lambda: sim.run(ROUNDS, resume=False)), sim.counters
 
 
 def _event(overlap):
@@ -106,7 +163,7 @@ def _event(overlap):
         sim = FLSimulation(_cfg(), run=RunConfig(
             server="event", staleness="weighted", staleness_lambda=1.0,
             overlap_rounds=overlap))
-        return lambda: sim.run(ROUNDS, resume=False)
+        return (lambda: sim.run(ROUNDS, resume=False)), sim.counters
     return make
 
 
@@ -116,21 +173,37 @@ def _sweep():
     def tiny(scheme, classes, dist, seed):
         return _cfg(seed)
 
-    return lambda: run_seed_group("dcs", 9, "uniform", [0, 1], ROUNDS,
-                                  cfg_fn=tiny, overlap=True)
+    counters = trace.RoundCounters()
+    return (lambda: run_seed_group("dcs", 9, "uniform", [0, 1], ROUNDS,
+                                   cfg_fn=tiny, overlap=True,
+                                   counters=counters)), counters
 
 
-@pytest.mark.parametrize("make", [_serial, _event(False), _event(True),
-                                  _sweep],
-                         ids=["serial", "event", "event-ahead", "sweep"])
+DRIVERS = [_serial, _event(False), _event(True), _sweep]
+DRIVER_IDS = ["serial", "event", "event-ahead", "sweep"]
+
+
+@pytest.mark.parametrize("make", DRIVERS, ids=DRIVER_IDS)
 def test_every_driver_emits_the_round_spans(make, tmp_path):
     """The serial driver, the event server (its own cohort path) both
     ways, and the seed-group sweep: one step per round holding the
     fence, cohort, dispatch and read spans (one of each per seed)."""
-    by_round = _spans_by_round(_profile(make(), tmp_path))
+    run, _ = make()
+    by_round = _spans_by_round(_profile(run, tmp_path))
     assert sorted(by_round) == list(range(ROUNDS))
     for names in by_round.values():
         assert set(CHILD_SPANS) <= set(names), names
+
+
+@pytest.mark.parametrize("make,behind", [(_ahead, ROUNDS - 1)]
+                         + [(m, 0) for m in DRIVERS],
+                         ids=["round-ahead"] + DRIVER_IDS)
+def test_evals_behind_prefix_counts_round_ahead_only(make, behind):
+    """Only the round-ahead driver queues an eval behind the next
+    round's prefix: every round but the last."""
+    run, counters = make()
+    run()
+    assert counters.evals_behind_prefix == behind
 
 
 def test_counters_count_a_padded_odd_cohort():
