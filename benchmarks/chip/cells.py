@@ -9,16 +9,24 @@ files live beside this module:
   limits of the numbers ``correct`` compares;
 - ``traffic/<name>.json``: how the driver is run (warm-up rounds,
   rounds checked) and its ``RunConfig`` overrides;
-- ``metrics/<name>.json``: one per-layer metric's source and arithmetic.
+- ``metrics/<name>.json``: one per-layer metric's source and arithmetic;
+- ``families/<name>.py``: the model a configuration trains, named by its
+  ``"family"`` key: the program's ``FLSimConfig``, the data the
+  reference reads, the model's stages of the reference and its required
+  work (``families/cnn.py`` lists what every family exposes).
 
 Nothing here calls a program default: a later change to one cannot move
 the yardstick.
 """
 from __future__ import annotations
 
+import hashlib
+import importlib.util
 import json
 import os
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, Optional
 
 HERE = Path(__file__).resolve().parent
@@ -68,30 +76,41 @@ def resolve(workload: str, root: Path = ROOT) -> Dict:
     layer = [dict(m, spec=load_json(here / "metrics" / f"{m['name']}.json"))
              for m in bench["per_layer"] if reports(m)]
     return {"bench": bench, "cell": w, "config": conf, "traffic": traffic,
-            "end_to_end": e2e, "per_layer": layer}
+            "family": family(conf, root), "end_to_end": e2e,
+            "per_layer": layer}
 
 
-def sim_config(conf: Dict):
-    """The ``FLSimConfig`` of a configuration file.
+def family(conf: Dict, root: Path = ROOT) -> ModuleType:
+    """The module of the model ``conf`` trains: ``families/<name>.py``
+    beside this module in ``root``'s checkout, ``<name>`` its
+    ``"family"`` key.  Loaded by path, so a checkout runs only its own,
+    and once per path in a process."""
+    if "family" not in conf:
+        raise KeyError(f"configuration {conf.get('name')!r} has no "
+                       f"\"family\" key naming families/<name>.py")
+    rel = HERE.relative_to(ROOT) / "families" / f"{conf['family']}.py"
+    path = (Path(root) / rel).resolve()
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {conf.get('name')!r} names "
+                                f"family {conf['family']!r}, but {rel} is "
+                                f"not in {root}")
+    name = "_bench_family_" + hashlib.sha1(str(path).encode()).hexdigest()
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[name] = module
+        try:
+            spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
-    The deployment (data, partition, placement, speeds, slowdowns, the
-    initial model and the channel realizations of every round) is the
-    configuration's own ``deployment_seed``, so every run does the same
-    work.  Any input the rounds read decides which vehicles are elected
-    and meet Eq. 6, so it would change the work; a run's ``--seed``
-    draws only the rounds the check compares."""
-    from repro.fl.mobility import MobilityConfig
-    from repro.fl.network import NetworkConfig
-    from repro.fl.partition import PartitionConfig
-    from repro.fl.rounds import FLSimConfig
-    d = conf["deployment_seed"]
-    sim = dict(conf["sim"])
-    sim["slowdown_range"] = tuple(sim["slowdown_range"])
-    return FLSimConfig(
-        **sim, seed=d,
-        partition=PartitionConfig(**conf["partition"], seed=d),
-        mobility=MobilityConfig(**conf["mobility"], seed=d),
-        network=NetworkConfig(**conf["network"], seed=d))
+
+def sim_config(conf: Dict, root: Path = ROOT):
+    """The program's ``FLSimConfig`` of a configuration file, as its
+    family builds it."""
+    return family(conf, root).sim_config(conf)
 
 
 def run_config(conf: Dict, traffic: Dict):

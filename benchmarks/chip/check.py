@@ -19,23 +19,25 @@ and are compared exactly, with the limit 0:
 - ``elect_diff``, ``survivor_diff``: vehicles on which the election (on
   the program's positions and evaluations) and the deadline (on its
   mask) disagree;
-- ``acc_outside``: test images by which the accuracy count falls
-  outside the reference's interval: its sure count, plus the images
+- ``acc_outside``: test items by which the accuracy count falls
+  outside the reference's interval: its sure count, plus the items
   whose top two logits lie within ``MARGIN`` (a rounding can flip those
   either way).
 
-The matmul stages carry the precision; their limits lie between the
+The model's stages come from the configuration's family
+(``World.family``); the rest is the same for every model.  The matmul
+stages carry the precision; their limits lie between the
 program's readings and those of the control or of a planted fault
 (``control.py``, ``PERF.md``):
 
 - ``lf_gap``: the Eq. 7 probe loss, widest gap over vehicles (nats);
-- ``param_gap``: the global params after FedAvg, taken by the worst leaf
-  as the gap between the norms of the program's and the reference's
-  change of that leaf, over the larger of the reference's norm for that
-  leaf and for the median leaf.  Norms, not the norm of the difference:
-  local SGD over tens of steps can amplify last-bit differences into a
-  different trajectory of the same size.  A round that returns its
-  params unchanged reads 1;
+- ``param_gap``: the global params after FedAvg, taken by the worst
+  trained leaf (the family's ``trained_leaves``) as the gap between the
+  norms of the program's and the reference's change of that leaf, over
+  the larger of the reference's norm for that leaf and for the median
+  leaf.  Norms, not the norm of the difference: local SGD over tens of
+  steps can amplify last-bit differences into a different trajectory of
+  the same size.  A round that returns its params unchanged reads 1;
 - ``fit_gap``: the mean loss of the global params after FedAvg on the
   round's survivors' own training rows, program against reference, over
   the same loss of the params that entered the round.  Two trajectories
@@ -64,12 +66,12 @@ def off(a, b, scale) -> int:
     return int(np.sum(np.abs(a - b) > tol))
 
 
-def param_gap(p_in, p_out, p_ref) -> float:
+def param_gap(leaves, p_in, p_out, p_ref) -> float:
     """Worst leaf's ``| |p_out - p_in| - |p_ref - p_in| |`` over
-    ``max(|p_ref - p_in|, median leaf's |p_ref - p_in|)``.  When the
-    reference moved nothing (no survivor), anything but the unchanged
-    params reads 1."""
-    pin, pout, pref = (ref.params_leaves(t) for t in (p_in, p_out, p_ref))
+    ``max(|p_ref - p_in|, median leaf's |p_ref - p_in|)``, over the
+    ``leaves(params)`` that train.  When the reference moved nothing (no
+    survivor), anything but the unchanged params reads 1."""
+    pin, pout, pref = (leaves(t) for t in (p_in, p_out, p_ref))
     change = [np.linalg.norm(r - i) for r, i in zip(pref, pin)]
     floor = float(np.median(change))
     if floor == 0.0:
@@ -85,10 +87,7 @@ def fit_gap(world: ref.World, survivors, p_in, p_out, p_ref) -> float:
     ids = np.nonzero(np.asarray(survivors))[0]
     if len(ids) == 0:
         return 0.0
-    x = np.concatenate([world.images[i][:world.n_valid[i]] for i in ids])
-    y = np.concatenate([world.labels[i][:world.n_valid[i]] for i in ids])
-    fit = [float(np.mean(np.asarray(ref.sample_losses(p, x, y), np.float64)))
-           for p in (p_in, p_out, p_ref)]
+    fit = [world.family.fit_loss(world, ids, p) for p in (p_in, p_out, p_ref)]
     return abs(fit[1] - fit[2]) / fit[0]
 
 
@@ -97,7 +96,7 @@ def compare_round(world: ref.World, conf: Dict, rnd: int, p_in,
     """The numbers of one round.  ``out`` is what the program (or the
     control in its place) produced: ``pos``, ``feats``, ``evals``,
     ``mask``, ``survivors``, ``params`` and the accuracy ``count``."""
-    sim = conf["sim"]
+    sim, fam = conf["sim"], world.family
     pos = ref.positions(world, conf, rnd)
     k_pred, k_up = ref.round_keys(world, conf, rnd)
     feats = np.asarray(out["feats"], np.float64)
@@ -106,8 +105,7 @@ def compare_round(world: ref.World, conf: Dict, rnd: int, p_in,
                                                      np.float32)], axis=1)
     reach = (conf["mobility"]["road_length_m"] + conf["mobility"]["v_max_mps"]
              * rnd * sim["deadline_s"])
-    lo, hi = ref.count_correct(out["params"], world.test_images,
-                               world.test_labels, margin=MARGIN)
+    lo, hi = fam.count_correct(world, out["params"], margin=MARGIN)
     p_ref = ref.fedavg_round(world, conf, p_in, out["survivors"], rnd)
     return {
         "pos_diff": off(out["pos"], pos, reach),
@@ -122,8 +120,9 @@ def compare_round(world: ref.World, conf: Dict, rnd: int, p_in,
         "acc_outside": max(0, lo - int(out["count"]),
                            int(out["count"]) - hi),
         "lf_gap": float(np.max(np.abs(
-            feats[:, 3] - np.asarray(ref.probe_loss(world, conf, p_in))))),
-        "param_gap": param_gap(p_in, out["params"], p_ref),
+            feats[:, 3] - np.asarray(fam.probe_loss(world, conf, p_in))))),
+        "param_gap": param_gap(fam.trained_leaves, p_in, out["params"],
+                               p_ref),
         "fit_gap": fit_gap(world, out["survivors"], p_in, out["params"],
                            p_ref),
     }

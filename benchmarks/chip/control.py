@@ -68,10 +68,8 @@ def with_params(world, o, params) -> dict:
     """The program's round with ``params`` in place of its own, and the
     accuracy read from them."""
     import jax
-    from benchmarks.chip import reference as ref
     with jax.default_matmul_precision("highest"):
-        count = ref.count_correct(params, world.test_images,
-                                  world.test_labels)[0]
+        count = world.family.count_correct(world, params)[0]
     return dict(o, params=params, count=count)
 
 

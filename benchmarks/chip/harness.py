@@ -32,7 +32,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from benchmarks.chip import cells, check, metrics, work, xplane
+from benchmarks.chip import cells, check, metrics, xplane
 from benchmarks.chip import reference as ref
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -208,7 +208,7 @@ def build(resolved: Dict):
     jax.config.update("jax_default_matmul_precision",
                       conf["matmul_precision"])
     t = [time.perf_counter()]
-    sim = FLSimulation(cells.sim_config(conf),
+    sim = FLSimulation(resolved["family"].sim_config(conf),
                        run=cells.run_config(conf, traffic))
     t.append(time.perf_counter())
     buckets = warm_training(sim, conf)
@@ -228,23 +228,16 @@ def build(resolved: Dict):
 # the check
 # --------------------------------------------------------------------------
 
-def world_of(sim) -> ref.World:
-    """The round's inputs as host arrays, one entry per vehicle."""
-    import jax
-    images, labels = [None] * sim.n, [None] * sim.n
-    for g in sim.groups:
-        gi, gl = np.asarray(g.images), np.asarray(g.labels)
-        for row, i in enumerate(g.client_ids):
-            images[i], labels[i] = gi[row], gl[row]
+def world_of(sim, family) -> ref.World:
+    """The round's inputs as host arrays, one entry per vehicle; the
+    family copies its own data."""
     return ref.World(
-        images=images, labels=labels,
         n_valid=np.asarray(sim.n_valid, np.int64),
         slowdown=np.asarray(sim.slowdown), x0=np.asarray(sim.mobility.x0),
         speeds=np.asarray(sim.mobility.speeds),
         jitter_phase=np.asarray(sim.mobility._jitter_phase),
-        test_images=np.asarray(jax.device_get(sim.test_images)),
-        test_labels=np.asarray(jax.device_get(sim.test_labels)),
-        seed=int(sim.cfg.seed), net_seed=int(sim.cfg.network.seed))
+        seed=int(sim.cfg.seed), net_seed=int(sim.cfg.network.seed),
+        data=family.world_data(sim), family=family)
 
 
 def replay(sim, clock: RoundClock, n_test: int) -> Dict[int, Dict]:
@@ -302,23 +295,18 @@ def check_rounds(world: ref.World, conf: Dict, outs: Dict[int, Dict],
 # --------------------------------------------------------------------------
 
 def window_work(conf: Dict, world: ref.World, clock: RoundClock) -> Dict:
-    """Required work of the window's rounds, from shapes and decisions:
-    the probe and the accuracy read every round, and the local SGD of
-    every vehicle the round trained (the Eq. 6 survivors of its mask)."""
-    s = conf["sim"]
+    """Required work of the window's rounds, from shapes and decisions,
+    as the family counts it: the probe and the accuracy read every
+    round, and the local SGD of every vehicle the round trained (the
+    Eq. 6 survivors of its mask)."""
     rounds = len(clock.times)
-    probe = work.probe_work(world.n_valid, s["probe_samples"])
-    trained_rows = 0
+    trained: List[int] = []
     for rnd, mask in enumerate(clock.masks[:rounds]):
         _, k_up = ref.round_keys(world, conf, rnd)
         who = ref.deadline(world, conf, ref.positions(world, conf, rnd),
                            mask, k_up)
-        trained_rows += int(world.n_valid[who].sum())
-    return {"probe_flops": probe["flops"] * rounds,
-            "probe_bytes": probe["bytes"] * rounds,
-            "train_flops": trained_rows * s["local_epochs"]
-            * work.TRAIN_FLOPS_PER_ROW,
-            "eval_flops": work.eval_flops(len(world.test_labels)) * rounds}
+        trained.extend(np.nonzero(who)[0].tolist())
+    return world.family.work(conf, world, rounds, trained)
 
 
 def keep_rounds(seed: int, est: int, k: int) -> List[int]:
@@ -373,15 +361,15 @@ def measure(resolved: Dict, seed: int, seconds: float, trace: bool,
         f"in the window, rounds by survivors {agg.tolist()}, checked "
         f"rounds {sorted(clock.kept)}")
     return {"sim": sim, "clock": clock, "counter": counter,
-            "setup_s": setup_s, "tdir": tdir}
+            "setup_s": setup_s, "tdir": tdir, "family": resolved["family"]}
 
 
 def collect(run: Dict) -> Dict:
     """After the window: the kept rounds replayed, the world on the host,
     and the program's device state let go."""
-    sim, clock = run["sim"], run["clock"]
-    outs = replay(sim, clock, int(sim.test_labels.shape[0]))
-    world = world_of(sim)
+    sim, clock, fam = run["sim"], run["clock"], run["family"]
+    outs = replay(sim, clock, fam.n_test(sim))
+    world = world_of(sim, fam)
     clock.kept.clear()
     clock.sim = run["sim"] = None
     del sim

@@ -1,32 +1,35 @@
 """A plain reference of one FL round, in float32 at ``highest`` precision.
 
 It imports nothing of the program.  It reads the cell's world (each
-vehicle's samples, its slowdown and mobility constants, the test set)
-and the configuration file, and recomputes the round as the paper and
-the configuration define it:
+vehicle's slowdown and mobility constants, and the family's data: each
+vehicle's samples and the test set) and the configuration file, and
+recomputes the round as the paper and the configuration define it:
 
 - positions on the wrap-around freeway (closed-form speed jitter);
 - the throughput predictor: Reno AIMD over 64 RTTs against a loss
   probability that rises toward the cell edge, averaged over the last
   16 windows;
-- the Eq. 7 probe: the paper CNN's mean cross-entropy over each
-  vehicle's first ``probe_samples`` samples;
+- the Eq. 7 probe: the model's mean loss over each vehicle's first
+  ``probe_samples`` samples (the family's ``probe_loss``);
 - Eq. 8 max-scaling and Mamdani inference over the 81-rule base
   (min conjunction, max aggregation, centre of gravity on [0, 100]);
 - the DCS election: a vehicle above ``e_tau`` elects itself when fewer
   than ``top_m`` in-range vehicles above ``e_tau`` rank above it;
 - the Eq. 6 deadline: ``E * C_i * |D_i| * B_exe / B + upload``;
-- Eq. 1 local SGD on each survivor and the Eq. 2 FedAvg;
-- the accuracy count on the test set.
+- Eq. 1 local SGD on each survivor (the family's ``local_train``) and
+  the Eq. 2 FedAvg;
+- the accuracy count on the test set (the family's ``count_correct``).
 
-The random draws (channel shadowing, Reno losses, epoch shuffles) use
-the seed schedule the configuration documents, so a round's
-randomness is the same realization the program draws.
+The model is the configuration's family (``families/<name>.py``, which
+``World.family`` holds); everything else here is the same for every
+model.  The random draws (channel shadowing, Reno losses, epoch
+shuffles) use the seed schedule the configuration documents, so a
+round's randomness is the same realization the program draws.
 
 Run under ``jax.default_matmul_precision("highest")`` it is the
 reference.  ``dtype=bfloat16`` at the default precision is the control:
-the model (CNN forward, training, FedAvg) and the fuzzy evaluator
-computed in the next precision below the configuration's float32.
+the model (forward, training, FedAvg) and the fuzzy evaluator computed
+in the next precision below the configuration's float32.
 """
 from __future__ import annotations
 
@@ -34,113 +37,40 @@ import functools
 import itertools
 import json
 from dataclasses import dataclass
-from typing import Dict, List, Sequence
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 F32 = jnp.float32
-BLOCK = 1024            # rows per forward block (keeps activations small)
 
 
 @dataclass
 class World:
-    """The data a round reads, on the host.  Per vehicle ``i``: its
-    samples ``images[i]`` (cap_i, 28, 28, 1) with ``n_valid[i]`` valid
-    leading rows; ``slowdown``; the mobility constants."""
-    images: List[np.ndarray]
-    labels: List[np.ndarray]
+    """The data a round reads, on the host.  Per vehicle ``i``:
+    ``n_valid[i]`` valid samples, ``slowdown``, the mobility constants.
+    ``data`` is the family's own (each vehicle's samples and the test
+    set, as ``family.world_data`` copies them); ``family`` is the module
+    of the model the configuration trains."""
     n_valid: np.ndarray
     slowdown: np.ndarray
     x0: np.ndarray
     speeds: np.ndarray
     jitter_phase: np.ndarray
-    test_images: np.ndarray
-    test_labels: np.ndarray
     seed: int                    # the deployment: init, training keys
     net_seed: int                # the channel realizations
+    data: Any
+    family: Any
 
     @property
     def n(self) -> int:
         return len(self.n_valid)
 
 
-# --------------------------------------------------------------------------
-# the paper CNN
-# --------------------------------------------------------------------------
-
-def forward(p: Dict, x: jax.Array) -> jax.Array:
-    """(B, 28, 28, 1) -> logits (B, 10): SAME 5x5 convs with ReLU and 2x2
-    max-pooling, then two dense layers."""
-    for name in ("conv1", "conv2"):
-        x = jax.lax.conv_general_dilated(
-            x, p[name]["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + p[name]["b"]
-        x = jax.nn.relu(x)
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                  (1, 2, 2, 1), "VALID")
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ p["fc1"]["w"] + p["fc1"]["b"])
-    return x @ p["fc2"]["w"] + p["fc2"]["b"]
-
-
-def nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    """Per-sample cross-entropy."""
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jax.nn.logsumexp(logits, axis=-1) - gold
-
-
 def cast(tree, dtype):
+    """``tree`` with every leaf in ``dtype``."""
     return jax.tree.map(lambda a: jnp.asarray(a, dtype), tree)
-
-
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _block_losses(p, x, y, dtype):
-    return nll(forward(cast(p, dtype), x.astype(dtype)), y).astype(dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("dtype", "margin"))
-def _block_correct(p, x, y, dtype, margin):
-    logits = forward(cast(p, dtype), x.astype(dtype))
-    top2 = jax.lax.top_k(logits, 2)[0]
-    near = (top2[:, 0] - top2[:, 1] < margin) & (y >= 0)
-    right = (jnp.argmax(logits, -1) == y) & ~near
-    return right.sum(), near.sum()
-
-
-def _blocks(n: int):
-    for s in range(0, n, BLOCK):
-        yield s, min(s + BLOCK, n)
-
-
-def _padded(a: np.ndarray, s: int, e: int) -> np.ndarray:
-    blk = a[s:e]
-    if e - s < BLOCK:
-        pad = np.zeros((BLOCK - (e - s),) + a.shape[1:], a.dtype)
-        blk = np.concatenate([blk, pad])
-    return blk
-
-
-def sample_losses(p, x: np.ndarray, y: np.ndarray, dtype=F32) -> jax.Array:
-    """Per-sample losses of ``p`` over host rows, block by block."""
-    out = [_block_losses(p, _padded(x, s, e), _padded(y, s, e),
-                         dtype=dtype)[:e - s] for s, e in _blocks(len(y))]
-    return jnp.concatenate(out)
-
-
-def count_correct(p, x: np.ndarray, y: np.ndarray, dtype=F32,
-                  margin: float = 0.0):
-    """``(lo, hi)``: test images ``p`` classifies right for certain, and
-    that count plus the images whose top two logits lie within
-    ``margin`` of each other (a rounding could flip those either way)."""
-    right = near = 0
-    for s, e in _blocks(len(y)):
-        xb, yb = _padded(x, s, e), _padded(y, s, e).copy()
-        yb[e - s:] = -1                      # padding never counts
-        r, n = _block_correct(p, xb, yb, dtype=dtype, margin=margin)
-        right, near = right + int(r), near + int(n)
-    return right, right + near
 
 
 # --------------------------------------------------------------------------
@@ -225,18 +155,6 @@ def throughput(conf: Dict, pos: jax.Array, k_pred) -> jax.Array:
     return window.mean(axis=0) * 8.0 * net["packet_bytes"] / net["rtt_s"]
 
 
-def probe_loss(world: World, conf: Dict, p, dtype=F32) -> jax.Array:
-    """Eq. 7 per vehicle: mean loss over its first ``probe_samples``."""
-    take = np.minimum(world.n_valid, conf["sim"]["probe_samples"])
-    x = np.concatenate([im[:t] for im, t in zip(world.images, take)])
-    y = np.concatenate([lb[:t] for lb, t in zip(world.labels, take)])
-    seg = np.repeat(np.arange(world.n), take)
-    losses = sample_losses(p, x, y, dtype)
-    tot = jax.ops.segment_sum(losses, jnp.asarray(seg),
-                              num_segments=world.n)
-    return (tot / jnp.maximum(jnp.asarray(take, dtype), 1)).astype(F32)
-
-
 def rule_table():
     """The 81 rules: antecedent levels (SQ, TA, CC, LF), 0 the worst, and
     the consequent L0..L8 — the sum of the four levels, except when TA
@@ -308,35 +226,6 @@ def deadline(world: World, conf: Dict, pos: jax.Array, mask, k_up
     return (np.asarray(mask) > 0) & np.asarray(ok)
 
 
-@functools.partial(jax.jit, static_argnames=("epochs", "batch", "steps",
-                                             "lr", "dtype"))
-def _local_sgd(p, images, labels, n_valid, key, *, epochs, batch, steps,
-               lr, dtype):
-    """Eq. 1: ``epochs`` passes, each over a fresh permutation of the
-    vehicle's rows in ``steps`` batches; padding rows carry no loss."""
-    cap = images.shape[0]
-    p = cast(p, dtype)
-    images = images.astype(dtype)
-
-    def loss(q, x, y, m):
-        return (nll(forward(q, x), y) * m).sum() / jnp.maximum(m.sum(), 1)
-
-    def epoch(q, ekey):
-        perm = jax.random.permutation(ekey, cap)
-        x, y = images[perm], labels[perm]
-        m = (perm < n_valid).astype(dtype)
-
-        def step(q, i):
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * batch, batch)
-            g = jax.grad(loss)(q, sl(x), sl(y), sl(m))
-            return jax.tree.map(lambda a, b: a - jnp.asarray(lr, dtype) * b,
-                                q, g), None
-
-        return jax.lax.scan(step, q, jnp.arange(steps))[0], None
-
-    return jax.lax.scan(epoch, p, jax.random.split(key, epochs))[0]
-
-
 def train_key(world: World, rnd: int, i: int):
     """Vehicle ``i``'s key in round ``rnd``: ``PRNGKey(seed + 2)`` with
     the round, then the vehicle, folded in."""
@@ -348,19 +237,12 @@ def fedavg_round(world: World, conf: Dict, p, survivors, rnd: int,
                  dtype=F32):
     """Eq. 1 on every survivor from the global ``p``, then Eq. 2: the
     average weighted by ``|D_i|``.  No survivor leaves ``p`` as it is."""
-    sim = conf["sim"]
     ids = np.nonzero(np.asarray(survivors))[0]
     if len(ids) == 0:
         return p
     acc, tot = None, 0.0
     for i in ids:
-        cap = world.images[i].shape[0]
-        batch = min(sim["batch_size"], cap)
-        q = _local_sgd(p, world.images[i], world.labels[i],
-                       jnp.int32(world.n_valid[i]), train_key(world, rnd, i),
-                       epochs=sim["local_epochs"], batch=batch,
-                       steps=max(1, cap // sim["batch_size"]),
-                       lr=sim["lr"], dtype=dtype)
+        q = world.family.local_train(world, conf, p, i, rnd, dtype)
         w = jnp.asarray(float(world.n_valid[i]), dtype)
         acc = jax.tree.map(lambda a: w * a, q) if acc is None else \
             jax.tree.map(lambda a, b: a + w * b, acc, q)
@@ -381,16 +263,12 @@ def run_round(world: World, conf: Dict, p, rnd: int, dtype=F32) -> Dict:
     feats = jnp.stack([jnp.asarray(world.n_valid, F32),
                        throughput(conf, pos, k_pred),
                        1.0 / jnp.asarray(world.slowdown, F32),
-                       probe_loss(world, conf, p, dtype)], axis=1)
+                       world.family.probe_loss(world, conf, p, dtype)],
+                      axis=1)
     evals = fuzzy(conf, feats, dtype=dtype)
     mask = elect(conf, pos, evals)
     surv = deadline(world, conf, pos, mask, k_up)
     out = fedavg_round(world, conf, p, surv, rnd, dtype)
     return {"pos": pos, "feats": feats, "evals": evals, "mask": mask,
             "survivors": surv, "params": out,
-            "count": count_correct(out, world.test_images,
-                                   world.test_labels, dtype)[0]}
-
-
-def params_leaves(p) -> Sequence[np.ndarray]:
-    return [np.asarray(a, np.float64) for a in jax.tree.leaves(p)]
+            "count": world.family.count_correct(world, out, dtype)[0]}

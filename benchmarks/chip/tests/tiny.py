@@ -19,5 +19,6 @@ def resolved(workload: str = "tiny.sync") -> dict:
              for m in bench["per_layer"]]
     return {"bench": bench, "cell": {"name": workload, "config": "tiny",
                                      "traffic": "sync", "chips": 1},
-            "config": conf, "traffic": traffic, "end_to_end": e2e,
+            "config": conf, "traffic": traffic,
+            "family": cells.family(conf), "end_to_end": e2e,
             "per_layer": layer}
